@@ -1,118 +1,45 @@
-"""JAX version compatibility shims.
+"""Choices every caller of the installed jax (0.9) makes the same way.
 
-The repo targets the current jax API (``jax.shard_map`` with ``check_vma``,
-``pltpu.InterpretParams``, ``pltpu.CompilerParams``, ``jax.sharding.AxisType``,
-``pltpu.sync_copy``); older jaxlibs (0.4.x) spell several of these differently
-or lack them.  Every call site goes through this module so the rest of the
-codebase is written against one API:
-
-  * :func:`shard_map`       — ``jax.shard_map`` or the ``jax.experimental``
-                              fallback, mapping ``check_vma`` -> ``check_rep``.
-  * :func:`make_mesh`       — ``jax.make_mesh`` with ``axis_types`` only when
-                              ``AxisType`` exists.
-  * :func:`interpret_params`— ``pltpu.InterpretParams(...)`` when available,
-                              else plain ``interpret=True`` (the generic pallas
-                              interpreter, which on 0.4.x already discharges
-                              remote DMAs under a single-axis shard_map).
-  * :func:`compiler_params` — ``pltpu.CompilerParams`` or ``TPUCompilerParams``.
-  * :func:`sync_copy`       — ``pltpu.sync_copy`` or an in-kernel element copy.
+  * :func:`make_mesh`         — ``jax.make_mesh`` with ``Auto`` axis types:
+                                the shard_map/``NamedSharding`` code in this
+                                repo is written for automatic sharding
+                                propagation, not explicit-axis typing.
+  * :func:`default_interpret` — how a Pallas TPU kernel runs when its caller
+                                does not say: the TPU interpreter on the CPU
+                                backend, Mosaic-compiled everywhere else.
+  * :func:`compiler_params`   — the Mosaic parameters of the four collective
+                                kernels, which stage whole operands in VMEM.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType
 
-# --------------------------------------------------------------- shard_map
-
-if hasattr(jax, "shard_map"):                        # jax >= 0.6
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:                                                # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` on new jax, experimental fallback on old jax.
-
-    Usable both as ``shard_map(f, mesh=...)`` and via ``functools.partial``
-    (decorator style), like the real thing.
-    """
-    kw = {_CHECK_KW: check_vma}
-    if f is None:
-        return functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-# --------------------------------------------------------------- mesh
 
 def make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` that tolerates jaxlibs without ``AxisType``."""
+    """``jax.make_mesh`` over ``devices`` (default: all) with Auto axes."""
     shape, axes = tuple(shape), tuple(axes)
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    try:
-        from jax.sharding import AxisType
-        kw["axis_types"] = (AxisType.Auto,) * len(axes)
-    except ImportError:
-        pass
-    return jax.make_mesh(shape, axes, **kw)
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
-def axis_size(name):
-    """``jax.lax.axis_size`` with a psum(1) fallback for old jax."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
+def default_interpret():
+    """``interpret=`` value for ``pl.pallas_call`` when the caller passes
+    none: ``pltpu.InterpretParams()`` (sender-driven remote-DMA simulator)
+    on the CPU backend, ``False`` (Mosaic) on any accelerator."""
+    return pltpu.InterpretParams() if jax.default_backend() == "cpu" else False
 
 
-def set_mesh(mesh):
-    """``jax.set_mesh`` context; old jax uses the Mesh object itself as a
-    context manager (enough for NamedSharding-carrying code paths)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+# The collective kernels stage whole operands in VMEM (ROADMAP S3), which
+# outgrows Mosaic's default scoped-VMEM limit (16 MiB on v5e) well before
+# the 128 MiB a v5e core has. Leave the rest to the compiler's own scratch.
+VMEM_LIMIT_BYTES = 100 * 2**20
 
 
-# --------------------------------------------------------------- pallas tpu
-
-def interpret_params(**kwargs):
-    """Interpret-mode marker for ``pl.pallas_call(interpret=...)``."""
-    if hasattr(pltpu, "InterpretParams"):
-        return pltpu.InterpretParams(**kwargs)
-    return True
-
-
-def compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams`` (old)."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
-def sync_copy(src_ref, dst_ref):
-    """``pltpu.sync_copy`` when present, else a direct in-kernel copy."""
-    if hasattr(pltpu, "sync_copy"):
-        return pltpu.sync_copy(src_ref, dst_ref)
-    dst_ref[...] = src_ref[...]
-
-
-# The legacy (0.4.x) pallas interpreter has no remote-signal discharge rule.
-LEGACY_INTERPRET = not hasattr(pltpu, "InterpretParams")
-
-
-def remote_semaphore_signal(sem, inc, *, device_id,
-                            device_id_type=pltpu.DeviceIdType.MESH):
-    """Signal a peer's semaphore; under the legacy interpreter fall back to a
-    local signal — the simulation is sequential, so cross-device credit flow
-    degenerates to per-device bookkeeping with identical counts."""
-    if LEGACY_INTERPRET:
-        pltpu.semaphore_signal(sem, inc)
-    else:
-        pltpu.semaphore_signal(sem, inc, device_id=device_id,
-                               device_id_type=device_id_type)
+def compiler_params():
+    """Mosaic parameters of the collective kernels. No ``collective_id``:
+    no kernel takes a custom barrier semaphore, and Mosaic's default device
+    barrier already orders every rank's kernel entry before remote DMAs."""
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)

@@ -29,10 +29,14 @@ with nobody watching):
 
 Batched evaluation (docs/search.md — the throughput half of ROADMAP open
 item 3): :meth:`CascadeEvaluator.evaluate_batch` evaluates a whole
-generation at once. l1 validity/build and l3 analytic costing are pure
-trace-time math per candidate; the expensive part — the l2 interpret
-execution — fans out across a bounded ``concurrent.futures`` worker pool
-(``batch_workers``). Each pool task runs the *same* guarded per-candidate
+generation at once: candidates fan out across a bounded
+``concurrent.futures`` worker pool (``batch_workers``), which overlaps
+their l0/l1 tracing and lowering and l3 costing. The l2 executions
+themselves, wall-clock timings included, run one at a time per process
+and to completion (``_L2_SLOT``): the TPU interpreter keeps
+process-global state that concurrent kernels corrupt, and a timing on a
+chip must not share it with another program. Each pool task runs the *same*
+guarded per-candidate
 cascade the sequential path runs (same ``_run_l2`` seam, same
 ``timeout_s``/quarantine discipline: the abandonable deadline thread stays
 per candidate, so a wedged candidate releases its pool slot at the
@@ -54,6 +58,37 @@ import jax
 import numpy as np
 
 from repro.core.design_space import Directive
+
+class _L2Slot:
+    """One l2 execution at a time in this process, held from dispatch to
+    completion: the Pallas TPU interpreter keeps a process-global shared
+    memory that a second concurrent kernel corrupts (observed as spurious
+    l2 errors under batched evaluation), and a wall-clock timing on a chip
+    must not overlap another program. The slot is owned by a candidate; a
+    candidate quarantined at its deadline hands it on, so a wedged
+    execution never stalls the candidates queued behind it."""
+
+    def __init__(self):
+        self._cv = threading.Condition()
+        self._owner = None
+
+    def acquire(self, owner, timeout=None) -> bool:
+        with self._cv:
+            if not self._cv.wait_for(lambda: self._owner is None, timeout):
+                return False
+            self._owner = owner
+            return True
+
+    def release(self, owner):
+        """Free the slot if ``owner`` holds it (a no-op otherwise: an
+        abandoned execution that comes back has already handed it on)."""
+        with self._cv:
+            if self._owner is owner:
+                self._owner = None
+                self._cv.notify_all()
+
+
+_L2_SLOT = _L2Slot()
 
 
 @dataclass
@@ -127,8 +162,9 @@ class CascadeEvaluator:
         ``records`` and the ``quarantine`` entries are identical to calling
         :meth:`evaluate` per candidate in order (wall timings aside).
 
-        The l2 interpret executions fan out across a bounded worker pool of
-        at most ``max_workers`` (default ``batch_workers``) threads; l1
+        Candidates fan out across a bounded worker pool of at most
+        ``max_workers`` (default ``batch_workers``) threads, their l2
+        executions serialized by ``_L2_SLOT``; l1
         build/lower and l3 analytic costing ride the same per-candidate
         pass (pure trace-time math — cheap and thread-safe). Each pool task
         keeps the sequential path's per-candidate ``timeout_s`` discipline:
@@ -165,8 +201,8 @@ class CascadeEvaluator:
     def _guarded(self, cand: Candidate, publish=True):
         """The full timeout-guarded cascade for one candidate: the body
         runs on a daemon thread; past ``timeout_s`` the candidate is
-        quarantined (the wedged thread is abandoned — it holds no locks
-        the search needs) and the caller moves on. Returns ``(result,
+        quarantined (the wedged thread is abandoned, and the l2 slot it
+        may hold is handed on) and the caller moves on. Returns ``(result,
         quarantine_entry_or_None)``; with ``publish=False`` nothing is
         appended to ``records``/``quarantine`` — the batch path replays
         publication in input order."""
@@ -183,6 +219,7 @@ class CascadeEvaluator:
         th = threading.Thread(target=run, daemon=True,
                               name=f"cascade-eval-{cand.cid}")
         t0 = time.perf_counter()
+        cand._deadline = t0 + self.timeout_s
         th.start()
         th.join(self.timeout_s)
         if th.is_alive():
@@ -194,6 +231,7 @@ class CascadeEvaluator:
             # flag first: the abandoned thread must not append a late
             # duplicate record if it ever comes back from the wedge
             cand._quarantined = True
+            _L2_SLOT.release(cand)
             res = EvalResult(0, 0.0, diagnostic=diag, quarantined=True,
                              rejection="quarantine")
             res = self._record(cand, res, {"quarantine": elapsed},
@@ -223,6 +261,24 @@ class CascadeEvaluator:
         """The l2 execution boundary — a deliberate seam: tests and fault
         suites wrap it to inject flaky executions or wire faults."""
         return jfn(*self.inputs)
+
+    def _take_l2_slot(self, cand) -> bool:
+        """Wait for the process's l2 slot. Past the candidate's deadline
+        (plus a margin, so the deadline watcher speaks first) the wait is
+        given up: the candidate has been quarantined. A quarantined
+        candidate never takes the slot again."""
+        if getattr(cand, "_quarantined", False):
+            return False
+        deadline = getattr(cand, "_deadline", None)
+        wait = (None if deadline is None
+                else max(0.0, deadline - time.perf_counter()) + 1.0)
+        return _L2_SLOT.acquire(cand, wait)
+
+    def _slot_lost(self, cand, levels, publish):
+        return self._record(
+            cand, EvalResult(1, 0.0, rejection="l2:queue",
+                             diagnostic="l2 slot not free before the "
+                             "deadline"), levels, publish=publish)
 
     def _verify_l0(self, d):
         """The l0 static-verification boundary — a seam like
@@ -313,20 +369,27 @@ class CascadeEvaluator:
         t2 = time.perf_counter()
         retries = 0
         while True:
+            if not self._take_l2_slot(cand):
+                return self._slot_lost(cand, levels, publish)
+            failure = None
             try:
-                out = self._run_l2(jfn)
-                break
+                out = jax.block_until_ready(self._run_l2(jfn))
             except Exception:
-                if retries >= self.l2_retries:
-                    levels["l2"] = time.perf_counter() - t2
-                    return self._record(
-                        cand, EvalResult(1, 0.0, retries=retries,
-                                         rejection="l2:execute",
-                                         diagnostic="l2 execution failed:\n"
-                                         + traceback.format_exc()[-1500:]),
-                        levels, publish=publish)
-                retries += 1
-                time.sleep(self.backoff_s * retries)
+                failure = traceback.format_exc()
+            finally:
+                _L2_SLOT.release(cand)
+            if failure is None:
+                break
+            if retries >= self.l2_retries:
+                levels["l2"] = time.perf_counter() - t2
+                return self._record(
+                    cand, EvalResult(1, 0.0, retries=retries,
+                                     rejection="l2:execute",
+                                     diagnostic="l2 execution failed:\n"
+                                     + failure[-1500:]),
+                    levels, publish=publish)
+            retries += 1
+            time.sleep(self.backoff_s * retries)
         tol = self.rtol
         if d.tunable("wire_i8", 0):
             tol = max(tol, 8e-2)          # quantized wire is lossy by design
@@ -376,8 +439,13 @@ class CascadeEvaluator:
         t_wall = float("inf")
         if self.wallclock:
             from repro.core.telemetry import wallclock_us
+            if not self._take_l2_slot(cand):
+                return self._slot_lost(cand, levels, publish)
             tw = time.perf_counter()
-            t_wall = wallclock_us(jfn, self.inputs) / 1e3
+            try:
+                t_wall = wallclock_us(jfn, self.inputs) / 1e3
+            finally:
+                _L2_SLOT.release(cand)
             levels["wallclock"] = time.perf_counter() - tw
         return self._record(
             cand, EvalResult(3, 10000.0 / (1.0 + t_eff), t_model_ms=t_ms,

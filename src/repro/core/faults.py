@@ -12,7 +12,7 @@ consumed at two cascade levels:
   faults (:data:`CORRUPT_WIRE`/:data:`TRUNCATED_WIRE`) are applied to the
   kernel output via :func:`inject_wire_fault` so the evaluator's
   finite/rel-err checks must classify them. A delayed-DMA straggler has no
-  l2 observable (the interpreter is lockstep-sequential by construction);
+  l2 observable (l2 checks values, and a late DMA lands the same values);
   it is charged at l3 and fed to the :class:`StragglerWatchdog` as wall
   time.
 * **l3 (analytic)** — :func:`fault_cost` prices the scenario: the degraded

@@ -25,6 +25,23 @@ class ChipSpec:
 
 V5E = ChipSpec()
 
+# device_kind (as jax reports it) -> spec. A TPU that is not listed here is
+# an error, never a default: a wrong spec silently re-ranks the search.
+CHIPS = {"TPU v5 lite": V5E}
+
+
+def chip_spec(device) -> ChipSpec:
+    """The spec of ``device``. Off the TPU (CPU tests, the described-
+    topology compiles) the l3 model targets v5e explicitly."""
+    if device.platform != "tpu":
+        return V5E
+    try:
+        return CHIPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no ChipSpec for TPU device_kind {device.device_kind!r}; "
+            f"known: {sorted(CHIPS)}") from None
+
 
 @dataclass(frozen=True)
 class HardwareContext:
@@ -55,7 +72,11 @@ class HardwareContext:
                 f"{self.chip.ici_link_bw/1e9:.0f} GB/s/link ICI")
 
 
-def extract_hardware_context(mesh, chip: ChipSpec = V5E) -> HardwareContext:
+def extract_hardware_context(mesh, chip: ChipSpec = None) -> HardwareContext:
+    """Deployment context of ``mesh``; ``chip`` defaults to the spec of the
+    mesh's devices (:func:`chip_spec`)."""
+    if chip is None:
+        chip = chip_spec(mesh.devices.flat[0])
     shape = tuple(mesh.shape[a] for a in mesh.axis_names)
     axes = tuple(mesh.axis_names)
     has_dcn = "pod" in axes and mesh.shape["pod"] > 1

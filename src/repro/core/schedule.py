@@ -17,21 +17,20 @@ cover the realization matrix:
   * :class:`RingSchedule` — ring-rotation rounds ``(step, chunk)`` for the
     ring workloads (ring_attention KV rotation, kv_shuttle K→V tiles).
 
-**The contract** (enforced at runtime by the legacy 0.4.x pallas
-interpreter's lockstep discharge, property-tested in
-``tests/test_schedules.py``):
+**The contract** (property-tested in ``tests/test_schedules.py`` and
+checked statically by ``core/verify.py``):
 
 1. ``rounds`` is a total, deterministic, rank-independent order; every
-   ``(edge, tile)`` event appears exactly once. Every rank issues every
-   round's DMA **unconditionally** (no role-predicated ``pl.when`` around
-   ``dma.start()``) and each round's edges form a permutation.
+   ``(edge, tile)`` event appears exactly once and each round's edges form
+   a permutation.
 2. ``send_window_depths(contexts)`` mirrors the kernels' bounded-issue
    algorithm: at most ``contexts`` rounds' send semaphores stay unawaited;
    the oldest is ``wait_send``-ed before the next round issues.
 3. ``issued_rounds()`` / ``completion_ticks()`` are the DMA-issue and
    receive-readiness counts the cost model charges ``TILE_SYNC`` per event.
-4. Receive-semaphore slots follow the :func:`sem_slot` convention — slot
-   ``s`` counts arrivals from source ``s`` under either semaphore engine.
+4. Receive semaphores with one slot per peer are indexed by the *sender's*
+   rank: the sender's descriptor names the slot its arrival lands in on
+   the receiver, and the receiver waits slot ``s`` for arrivals from ``s``.
 5. Numeric knobs drawn from ``design_space.TUNABLES`` need not divide a
    given shape: consumers repair them with :func:`sanitize_tile` (largest
    divisor) at their own boundary so a slow-path diff patch can never
@@ -50,7 +49,7 @@ __all__ = [
     "RingSchedule", "SendWindow", "make_schedule",
     "make_broadcast_schedule", "make_ring_schedule", "block_counts",
     "send_window_depths", "sanitize_tile", "sanitize_combine_tile",
-    "sanitize_tile_m", "sanitize_kv_chunk", "sem_slot", "check_live",
+    "sanitize_tile_m", "sanitize_kv_chunk", "check_live",
     "respill_counts",
 ]
 
@@ -196,22 +195,6 @@ def sanitize_kv_chunk(kv_chunk, rows):
     return sanitize_tile(kv_chunk, rows)
 
 
-def sem_slot(me, inbound_src):
-    """Receive-semaphore slot for an arrival from ``inbound_src``.
-
-    The convention is **slot s = edge from source rank s**. Under faithful
-    sender-driven RDMA (hardware, or the modern ``InterpretParams``
-    simulator) the *sender's* descriptor names the slot its signal lands in
-    on the receiver — the issuer's own rank (``me``). The legacy lockstep
-    discharge instead increments the slot named by the *receiver's* own
-    descriptor — its inbound peer for this round (``inbound_src``). Both
-    reduce to the same convention once routed through here; kernels with
-    per-edge semaphore arrays must use this (single-edge kernels like the
-    ring, whose receive semaphores are scalar per chunk slot, need not)."""
-    from repro.compat import LEGACY_INTERPRET
-    return inbound_src if LEGACY_INTERPRET else me
-
-
 class CollectiveSchedule:
     """Base contract: a trace-time lockstep round order plus accounting.
 
@@ -299,15 +282,16 @@ class DispatchSchedule(CollectiveSchedule):
                    for e in range(self.n) if e != rank)
 
     def dummy_wire_tokens(self, rank=0):
-        """Off-rank dummy (trash-row) tokens the lockstep interpreter path
-        additionally ships for rank ``rank``; elided on real hardware."""
+        """Off-rank dummy (trash-row) tokens the padded (interpret-mode)
+        schedule additionally ships for rank ``rank``; elided when the
+        kernel is compiled."""
         return sum((self.b_max - self.blocks[e]) * self.block_tokens
                    for e in range(self.n) if e != rank)
 
     def issued_rounds(self, elide_dummy=False):
-        """Dispatch ``dma_start`` rounds each rank issues: the legacy
-        interpreter's lockstep rule pads every edge to ``b_max`` rounds;
-        real hardware (``elide_dummy``) issues only the real microblocks
+        """Dispatch ``dma_start`` rounds each rank issues: the padded
+        schedule ships every edge ``b_max`` rounds; the compiled kernel
+        (``elide_dummy``) issues only the real microblocks
         (rank r's edge to expert e carries ``blocks[e]``, so the dispatch
         total is identical on every rank)."""
         if elide_dummy:

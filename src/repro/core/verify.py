@@ -3,11 +3,9 @@
 A symbolic per-rank executor over a :class:`CollectiveSchedule`'s round
 order that proves the schedule contract *without running a kernel*:
 
-* **deadlock freedom** — every semaphore wait has a matching signal under
-  the lockstep rule, and no DMA issue is role-predicated (the
-  ``repro/compat.py`` rule: the legacy 0.4.x lockstep interpreter cannot
-  discharge a ``pl.when``-guarded ``dma.start``);
-* **slot-reuse races** — a ``sem_slot`` / VMEM double-buffer slot is never
+* **deadlock freedom** — every semaphore wait has a matching signal in
+  the symbolic execution;
+* **slot-reuse races** — a receive-semaphore / VMEM double-buffer slot is never
   overwritten before its arrival tick is consumed, for every ``contexts``
   depth in ``TUNABLES``;
 * **window-cap and drain invariants** — the in-flight send depth never
@@ -32,10 +30,9 @@ Modeling notes (one deliberate simplification each):
 * A K/V chunk pair (and a data+scale pair) folds into one descriptor per
   round entry where the kernel `amend`s the window — the window depth
   and the signal counts are what the contract constrains.
-* Delivery is in-order per ``(src, dst, semaphore)`` — the lockstep
-  interpreter's semantics, and the strongest assumption any of the four
-  kernels makes (real-block-before-dummy consumption in moe_dispatch's
-  pipelined wait depends on it).
+* Delivery is in-order per ``(src, dst, semaphore)``. No kernel depends
+  on it for its data: moe_dispatch's receive slots are per microblock,
+  the ring kernels' per chunk, and gemm_allgather reads no landed rows.
 """
 from __future__ import annotations
 
@@ -52,8 +49,6 @@ from repro.core.schedule import (BroadcastSchedule, CollectiveSchedule,
 # code -> one-line description; docs/static-analysis.md renders this table
 # and tools/schedule_lint.py prints it under --catalog
 CHECKS = {
-    "role-predicated-dma": "a DMA issue is predicated on rank role — the "
-        "legacy lockstep interpreter cannot discharge it (compat.py rule)",
     "lockstep-order": "round order is not the lockstep total order: "
         "non-monotone round issue on a rank, or a round's send/receive "
         "multiset is not a balanced permutation over the live ranks",
@@ -77,8 +72,8 @@ CHECKS = {
 
 MUTATION_CLASSES = (
     "dropped_signal", "premature_slot_reuse", "window_overflow",
-    "dead_rank_dma", "non_conserving_respill", "role_predicated",
-    "reordered_round", "off_by_one_tick",
+    "dead_rank_dma", "non_conserving_respill", "reordered_round",
+    "off_by_one_tick",
 )
 
 # mutation class -> the checker code that must flag it (class-specific
@@ -90,7 +85,6 @@ EXPECTED_CODE = {
     "window_overflow": "window-overflow",
     "dead_rank_dma": "dead-rank-dma",
     "non_conserving_respill": "conservation",
-    "role_predicated": "role-predicated-dma",
     "reordered_round": "lockstep-order",
     "off_by_one_tick": "stale-read",
 }
@@ -127,7 +121,6 @@ class Op:
     rows: int = 0
     writes: tuple = ()
     reads: tuple = ()
-    predicate: object = None     # role predicate marker (contract violation)
     signals: bool = True         # dma only: bump the receive semaphore
     dummy: bool = False          # trash-row round (excluded from conservation)
     opens: bool = True           # dma only: opens a new window entry
@@ -332,9 +325,8 @@ def lower_dispatch(sched, contexts, *, wire_i8=False, tile_fused=False,
                         _ffn(r, src, 0, blocks[r])
         else:
             # pipelined SIGNAL: wait only the real blocks of an edge, run
-            # its FFN, then tick off the dummy residue (real microblocks
-            # precede dummies in the lockstep round order, so the partial
-            # wait consumes exactly the real deliveries)
+            # its FFN, then tick off the dummy residue (the kernel waits
+            # real and dummy microblocks on their own per-block slots)
             for r in range(n):
                 my = blocks[r]
                 for s in range(n):
@@ -807,12 +799,6 @@ def _static_errors(prog):
     live = set(prog.live)
     for r in range(prog.n):
         for idx, op in enumerate(prog.ops[r]):
-            if op.kind == "dma" and op.predicate is not None:
-                errs.append(VerifyError(
-                    "role-predicated-dma", r, idx,
-                    f"DMA issue at round {op.rnd} predicated on role "
-                    f"{op.predicate!r} — the legacy lockstep interpreter "
-                    f"cannot discharge it"))
             if op.kind in ("dma", "signal") and op.dst not in live:
                 errs.append(VerifyError(
                     "dead-rank-dma", r, idx,
@@ -911,7 +897,7 @@ def degrade_errors(parent, live_ranks, degraded):
 
 def verify_program(prog):
     """Run every check on one lowered :class:`Program`.  Static scans
-    (role predication, dead ranks, lockstep order, conservation) run
+    (dead ranks, lockstep order, conservation) run
     first and short-circuit the symbolic execution — a malformed program
     would only cascade noise through it."""
     errs = _static_errors(prog)
@@ -1001,9 +987,6 @@ def apply_mutation(prog, cls, rank=0):
     elif cls == "dead_rank_dma":
         i = find(lambda o: o.kind == "dma" and not o.dummy)
         ops[i] = dataclasses.replace(ops[i], dst=p.n)
-    elif cls == "role_predicated":
-        i = find(lambda o: o.kind == "dma")
-        ops[i] = dataclasses.replace(ops[i], predicate=rank)
     elif cls == "reordered_round":
         i = find(lambda o: o.kind == "dma" and o.opens)
         j = find(lambda o: o.kind == "dma" and o.opens
@@ -1035,7 +1018,7 @@ def mutation_corpus():
     bcast = lower_broadcast(make_broadcast_schedule(4, 256, 64, True), 2)
     host = {"dropped_signal": disp, "premature_slot_reuse": ring,
             "window_overflow": bcast, "dead_rank_dma": disp,
-            "role_predicated": bcast, "reordered_round": disp,
+            "reordered_round": disp,
             "off_by_one_tick": ring}
     entries = []
     for cls in MUTATION_CLASSES:

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.compat import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -60,8 +62,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_i, l_i, *, causal, scale,
 
 
 def flash_attention(q, k, v, *, causal=True, q_block=128, kv_block=128,
-                    interpret=True):
-    """q/k/v: (BH, S, hd) -> (BH, S, hd)."""
+                    interpret=None):
+    """q/k/v: (BH, S, hd) -> (BH, S, hd). ``interpret=None`` picks
+    :func:`repro.compat.default_interpret`."""
     BH, S, hd = q.shape
     Skv = k.shape[1]
     assert S % q_block == 0 and Skv % kv_block == 0, (S, Skv, q_block, kv_block)
@@ -84,5 +87,5 @@ def flash_attention(q, k, v, *, causal=True, q_block=128, kv_block=128,
             pltpu.VMEM((q_block,), jnp.float32),
             pltpu.VMEM((q_block,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(q, k, v)

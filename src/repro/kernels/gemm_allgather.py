@@ -12,10 +12,9 @@ source and destination offsets of every transfer coincide.
 ``moe_dispatch.DispatchSchedule``): rounds ``(off, t)`` where in round
 ``(off, t)`` rank ``r`` sends tile ``t`` of its slab to peer ``(r + off) %
 n`` and receives the matching tile from ``(r - off) % n`` — a shift
-permutation, so the legacy 0.4.x pallas interpreter discharges it in
-lockstep. The broadcast is *dense* (every rank ships every tile to every
+permutation. The broadcast is *dense* (every rank ships every tile to every
 peer), so unlike the MoE dispatch schedule there are no dummy rounds and
-nothing to elide: the lockstep schedule IS the hardware schedule.
+nothing to elide.
 
 **Placement realizations (design-space P):**
   TILE_FUSED — rounds are ordered tile-major: tile ``t``'s broadcast DMAs
@@ -38,8 +37,8 @@ replacing the old kernel's wait-everything-at-``t == nt-1`` drain.
 
 Per-edge semaphores: slot ``p`` of the send array counts outstanding sends
 to peer ``p``; slot ``s`` of the receive array counts arrivals from source
-``s`` (routed through ``_sem_slot`` — see docs/kernels.md for the legacy
-vs. sender-driven slot convention).
+``s`` (the sender's descriptor names slot ``me`` on the receiver). Arrivals
+are waited through a copy descriptor of the landed rows' size.
 """
 from __future__ import annotations
 
@@ -50,13 +49,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import (interpret_params, shard_map, sync_copy,
-                          compiler_params as tpu_compiler_params)
+from jax import shard_map
+
+from repro.compat import compiler_params, default_interpret
 # The schedule machinery is defined once, in repro.core.schedule (the
 # collective-schedule contract); re-exported here for the kernel's callers.
 from repro.core.schedule import (BroadcastSchedule, SendWindow,  # noqa: F401
-                                 make_broadcast_schedule, sanitize_tile_m,
-                                 sem_slot)
+                                 make_broadcast_schedule, sanitize_tile_m)
 
 
 # ------------------------------------------------------------------- kernel
@@ -70,47 +69,42 @@ def _ga_kernel(a_ref, b_ref, o_ref, atile, bbuf, ctile, ssem, rsem,
     me = jax.lax.axis_index(axis)
 
     # GEMM operands live in ANY (HBM): B is staged into VMEM once, each A
-    # tile per round — the interpreter tolerates direct ANY reads but
-    # Mosaic on real TPU requires DMA-staged VMEM operands.
-    sync_copy(b_ref, bbuf)
-
-    # Receive-slot convention routed through the shared contract helper
-    # (core/schedule.py::sem_slot): slot s = edge from source rank s,
-    # under either the legacy lockstep or the sender-driven engine.
-    def _sem_slot(inbound_src):
-        return sem_slot(me, inbound_src)
+    # tile per round — Mosaic computes on DMA-staged VMEM operands only.
+    pltpu.sync_copy(b_ref, bbuf)
 
     def edge_dma(off, rel, rows):
         """Round (off, .): ship rows [rel, rel+rows) of my slab to peer
         (me+off)%n; the matching inbound rows land from (me-off)%n."""
         peer = jax.lax.rem(me + off, n)
-        src = jax.lax.rem(me - off + n, n)
         rows0 = me * M_l + rel
         return pltpu.make_async_remote_copy(
             src_ref=o_ref.at[pl.ds(rows0, rows)],
             dst_ref=o_ref.at[pl.ds(rows0, rows)],
-            send_sem=ssem.at[peer], recv_sem=rsem.at[_sem_slot(src)],
+            send_sem=ssem.at[peer], recv_sem=rsem.at[me],
             device_id=peer, device_id_type=pltpu.DeviceIdType.MESH)
 
     def gemm_tile(t):
         # operands and result both stage through VMEM scratch (atile/bbuf
         # in, ctile out); a_ref/o_ref live in ANY
-        sync_copy(a_ref.at[pl.ds(t * tm, tm)], atile)
+        pltpu.sync_copy(a_ref.at[pl.ds(t * tm, tm)], atile)
         ctile[...] = jax.lax.dot_general(
             atile[...], bbuf[...], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(ctile.dtype)
-        sync_copy(ctile, o_ref.at[pl.ds(me * M_l + t * tm, tm)])
+        pltpu.sync_copy(ctile, o_ref.at[pl.ds(me * M_l + t * tm, tm)])
 
-    def wait_arrivals(off, rows):
+    def wait_arrivals(off, rel, rows):
+        """Tick rows [rel, rel+rows) of the slab from source (me-off)%n: a
+        copy descriptor of their size waits that source's receive slot."""
         recv_probe()
         src = jax.lax.rem(me - off + n, n)
-        pltpu.semaphore_wait(rsem.at[src], rows * N)
+        landed = o_ref.at[pl.ds(src * M_l + rel, rows)]
+        pltpu.make_async_copy(landed, landed, rsem.at[src]).wait()
 
     # contexts-deep send window over the trace-time round order (the shared
-    # schedule.SendWindow): every DMA is issued unconditionally (lockstep
-    # rule), the window only bounds how many rounds' send semaphores stay
-    # unawaited. An attached ScheduleProbe (core/trace.py) records the
-    # trace-time issue/wait order for the observed-vs-modeled check.
+    # schedule.SendWindow): every DMA is issued unconditionally, the window
+    # only bounds how many rounds' send semaphores stay unawaited. An
+    # attached ScheduleProbe (core/trace.py) records the trace-time
+    # issue/wait order for the observed-vs-modeled check.
     if probe is None:
         window = SendWindow(contexts)
         recv_probe = lambda: None
@@ -139,7 +133,7 @@ def _ga_kernel(a_ref, b_ref, o_ref, atile, bbuf, ctile, ssem, rsem,
 
     if sched.fused:
         # TILE_FUSED: tile t's broadcast issues the moment its GEMM ends,
-        # overlapping tile t+1's compute — lockstep (off, t) order.
+        # overlapping tile t+1's compute — (off, t) round order.
         for t in range(nt):
             gemm_tile(t)
             for off in range(1, n):
@@ -148,14 +142,14 @@ def _ga_kernel(a_ref, b_ref, o_ref, atile, bbuf, ctile, ssem, rsem,
                 # COUNTER per-tile ticks: consume tile t-1's arrivals from
                 # every peer while tile t's sends are still in flight
                 for off in range(1, n):
-                    wait_arrivals(off, tm)
+                    wait_arrivals(off, (t - 1) * tm, tm)
         window.drain()
         if counter:
             for off in range(1, n):          # the final tile's ticks
-                wait_arrivals(off, tm)
+                wait_arrivals(off, (nt - 1) * tm, tm)
         else:
             for off in range(1, n):          # per-edge SIGNAL drain
-                wait_arrivals(off, nt * tm)
+                wait_arrivals(off, 0, nt * tm)
     else:
         # DEFERRED: one whole-slab round per peer after the full GEMM,
         # same schedule object with rows_per_round = M_l.
@@ -165,7 +159,7 @@ def _ga_kernel(a_ref, b_ref, o_ref, atile, bbuf, ctile, ssem, rsem,
             issue(off, 0, M_l)
         window.drain()
         for off in range(1, n):
-            wait_arrivals(off, M_l)
+            wait_arrivals(off, 0, M_l)
 
 
 def gemm_allgather_sharded(a, b, *, axis, sched: BroadcastSchedule = None,
@@ -177,7 +171,8 @@ def gemm_allgather_sharded(a, b, *, axis, sched: BroadcastSchedule = None,
     ``n_dev``/``tile_m``/``fused`` knobs are consulted only to build one
     when ``sched`` is None. ``probe`` (a ``core/trace.py::ScheduleProbe``)
     records the trace-time DMA issue/wait order for the observed-vs-modeled
-    schedule check."""
+    schedule check. ``interpret=None`` picks
+    :func:`repro.compat.default_interpret`."""
     M_l, K = a.shape
     N = b.shape[1]
     if sched is None:
@@ -189,7 +184,6 @@ def gemm_allgather_sharded(a, b, *, axis, sched: BroadcastSchedule = None,
     kern = functools.partial(_ga_kernel, axis=axis, sched=sched,
                              counter=bool(counter), contexts=contexts,
                              probe=probe)
-    ip = interpret if interpret is not None else interpret_params()
     return pl.pallas_call(
         kern,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
@@ -202,18 +196,19 @@ def gemm_allgather_sharded(a, b, *, axis, sched: BroadcastSchedule = None,
             pltpu.SemaphoreType.DMA((sched.n,)),     # per-peer send slots
             pltpu.SemaphoreType.DMA((sched.n,)),     # per-source recv slots
         ],
-        interpret=ip,
-        compiler_params=tpu_compiler_params(collective_id=11),
+        interpret=default_interpret() if interpret is None else interpret,
+        compiler_params=compiler_params(),
     )(a, b)
 
 
 def gemm_allgather(a_shards, b, mesh, *, axis="x", tile_m=128, fused=True,
-                   counter=False, contexts=2, probe=None):
+                   counter=False, contexts=2, probe=None, interpret=None):
     """Global entry: a_shards (n, M_l, K) sharded over axis; b replicated.
     ``tile_m`` is sanitized to a divisor of M_l; ``counter`` selects
     per-tile completion ticks (the FLUX point) on the fused path. ``probe``
     (a ``core/trace.py::ScheduleProbe``) records the trace-time DMA
-    issue/wait order for ``probe.check(sched, contexts)``."""
+    issue/wait order for ``probe.check(sched, contexts)``. ``interpret`` as
+    in :func:`gemm_allgather_sharded`."""
     from jax.sharding import PartitionSpec as P
     n_dev = mesh.shape[axis]
     sched = make_broadcast_schedule(n_dev, a_shards.shape[1], tile_m, fused)
@@ -223,7 +218,7 @@ def gemm_allgather(a_shards, b, mesh, *, axis="x", tile_m=128, fused=True,
     def run(a, bb):
         out = gemm_allgather_sharded(a[0], bb, axis=axis, sched=sched,
                                      counter=counter, contexts=contexts,
-                                     probe=probe)
+                                     probe=probe, interpret=interpret)
         return out[None]
 
     return run(a_shards, b)

@@ -30,8 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import (LEGACY_INTERPRET, interpret_params, shard_map,
-                          compiler_params as tpu_compiler_params)
+from jax import shard_map
+
+from repro.compat import compiler_params, default_interpret
 from repro.core.schedule import (RingSchedule, SendWindow,  # noqa: F401
                                  make_ring_schedule)
 
@@ -42,8 +43,6 @@ def _shuttle_kernel(x_ref, wk_ref, wv_ref, ko_ref, vo_ref,
                     contexts, decode_rank, pure=False):
     me = jax.lax.axis_index(axis)
     nc, cr = sched.nc, sched.kv_chunk
-    dk = kbuf.shape[1]
-    chunk_elems = cr * dk
     rows_total = sched.rows                  # V half's base row (pure mode)
 
     def chunk_dma(buf, o_ref, ssem, rsem_slot, c, nchunks):
@@ -103,34 +102,29 @@ def _shuttle_kernel(x_ref, wk_ref, wv_ref, ko_ref, vo_ref,
                                        0, nc)])
             window.drain()
 
+    def arrived(o_ref, rsem_slot, c, nchunks):
+        """Wait chunks [c, c+nchunks) of ``o_ref`` through a copy
+        descriptor of their size on the chunk's receive semaphore."""
+        landed = o_ref.at[pl.ds(c * cr, nchunks * cr)]
+        pltpu.make_async_copy(landed, landed, rsem_slot).wait()
+
     def _decode():
         if sched.fused and counter:
             # COUNTER: tick arrivals off one chunk at a time
             for c in range(nc):
-                pltpu.semaphore_wait(krecv.at[c], chunk_elems)
-                pltpu.semaphore_wait(vrecv.at[c], chunk_elems)
+                arrived(ko_ref, krecv.at[c], c, 1)
+                arrived(vo_ref, vrecv.at[c], c, 1)
         elif sched.fused:
             for c in range(nc):      # SIGNAL: per-edge drain after the loop
-                pltpu.semaphore_wait(krecv.at[c], chunk_elems)
+                arrived(ko_ref, krecv.at[c], c, 1)
             for c in range(nc):
-                pltpu.semaphore_wait(vrecv.at[c], chunk_elems)
+                arrived(vo_ref, vrecv.at[c], c, 1)
         else:
-            pltpu.semaphore_wait(krecv.at[0], nc * chunk_elems)
-            pltpu.semaphore_wait(vrecv.at[0], nc * chunk_elems)
+            arrived(ko_ref, krecv.at[0], 0, nc)
+            arrived(vo_ref, vrecv.at[0], 0, nc)
 
-    if LEGACY_INTERPRET:
-        # The legacy interpreter discharges a remote DMA via an all_gather
-        # every rank must reach — role-predicated issue would deadlock. Run
-        # the full chain on BOTH ranks in lockstep: the decode rank's
-        # outgoing copy carries its (zero) projections but the discharge
-        # selects the prefill rank as source for the decode rank, and the
-        # prefill rank's spurious self-delivery is masked by the caller
-        # (outputs are only valid on the decode rank by contract).
-        _prefill()
-        _decode()
-    else:
-        pl.when(me != decode_rank)(_prefill)
-        pl.when(me == decode_rank)(_decode)
+    pl.when(me != decode_rank)(_prefill)
+    pl.when(me == decode_rank)(_decode)
 
 
 def kv_shuttle_sharded(x, wk, wv, *, axis, chained=True, fused=False,
@@ -159,7 +153,6 @@ def kv_shuttle_sharded(x, wk, wv, *, axis, chained=True, fused=False,
                              chained=chained, counter=counter,
                              contexts=contexts, decode_rank=decode_rank,
                              pure=pure)
-    ip = interpret if interpret is not None else interpret_params()
     return pl.pallas_call(
         kern,
         in_specs=[
@@ -177,8 +170,8 @@ def kv_shuttle_sharded(x, wk, wv, *, axis, chained=True, fused=False,
             pltpu.SemaphoreType.DMA,                 # v send
             pltpu.SemaphoreType.DMA((sched.nc,)),    # v per-chunk recv
         ],
-        interpret=ip,
-        compiler_params=tpu_compiler_params(collective_id=13),
+        interpret=default_interpret() if interpret is None else interpret,
+        compiler_params=compiler_params(),
     )(x, wk, wv)
 
 
@@ -211,17 +204,24 @@ def kv_cache_shuttle(kv, mesh, *, axis="x", chained=True, fused=False,
     ``serve/engine.py::prefill_remote`` rides). kv: (2, 2N, w) sharded over
     the 2-rank ``axis`` — the prefill rank's row holds the finished cache
     stacked ``[K; V]``, the decode rank's row is zeros. Returns (K, V) each
-    (2, N, w); row [1] (the decode rank) holds the shuttled cache."""
+    (2, N, w); row [1] (the decode rank) holds the shuttled cache.
+
+    Mosaic ships refs only in whole 128-lane rows, so a cache narrower
+    than that (``w`` = head_dim = 64) travels viewed as 128-lane rows; the
+    bytes, and where they land, are the same."""
     from jax.sharding import PartitionSpec as P
+    two_n, w = kv.shape[1:]
+    lanes = 128 if w % 128 and (two_n // 2 * w) % 128 == 0 else w
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(P(axis),),
                        out_specs=(P(axis), P(axis)), check_vma=False)
     def run(kvs):
         dummy = jnp.zeros((1, 1), kvs.dtype)
-        ko, vo = kv_shuttle_sharded(kvs[0], dummy, dummy, axis=axis,
-                                    chained=chained, fused=fused,
+        ko, vo = kv_shuttle_sharded(kvs[0].reshape(-1, lanes), dummy, dummy,
+                                    axis=axis, chained=chained, fused=fused,
                                     counter=counter, kv_chunk=kv_chunk,
                                     contexts=contexts, pure=True)
+        ko, vo = ko.reshape(-1, w), vo.reshape(-1, w)
         me = jax.lax.axis_index(axis)
         ko = jnp.where(me == 1, ko, 0.0)
         vo = jnp.where(me == 1, vo, 0.0)

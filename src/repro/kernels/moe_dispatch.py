@@ -17,18 +17,16 @@ microblocks; expert ``e``'s edges need ``b[e] = ceil(counts[e]/B)`` blocks.
 The analytic (l3) model credits the exact token counts; the executed
 schedule ships the block-rounded ones (see :func:`executed_wire_tokens`).
 
-**Permutation-round schedule.** The legacy pallas interpreter discharges a
-remote DMA only when every rank issues it in lockstep and the edges form a
-permutation (each rank exactly one incoming copy of one static size). The
-trace-time schedule therefore runs rounds ``(off, j)``: in round ``(off,
-j)`` rank ``r`` sends microblock ``j`` of its block for expert ``e = (r -
-off) % n`` — a shift permutation. ``off = 0`` is the self edge (local
-expert's tokens loop back without touching the wire — the self/remote split
-of the STREAM_SPLIT build, here inside the kernel). Ranks whose edge has
-fewer than ``j+1`` real blocks ship a dummy block into the receiver's trash
-row to keep the permutation total; on real TPU hardware (non-interpret)
-those slots are elided since lockstep issue is not required. Dummy blocks
-are accounted separately and never exceed the padded baseline's wire.
+**Permutation-round schedule.** The trace-time schedule runs rounds
+``(off, j)``: in round ``(off, j)`` rank ``r`` sends microblock ``j`` of
+its block for expert ``e = (r - off) % n`` — a shift permutation. ``off =
+0`` is the self edge (local expert's tokens loop back without touching the
+wire — the self/remote split of the STREAM_SPLIT build, here inside the
+kernel). In the padded schedule, ranks whose edge has fewer than ``j+1``
+real blocks ship a dummy block into the receiver's trash row to keep every
+round a full permutation; the compiled kernel elides those slots. Dummy
+blocks are accounted separately and never exceed the padded baseline's
+wire.
 
 **Completion (design-space K):** ``SIGNAL`` waits per-edge DMA receive
 semaphores — expert compute for the earliest-arriving peer starts while
@@ -43,16 +41,17 @@ expert FFN runs as a tiled GEMM loop over ``combine_tile``-row tiles and
 the combine remote-DMA for each output tile is issued the moment that tile
 is ready — instead of finishing the whole per-source FFN before any
 combine round. The trace-time round order ``(off, j, t)`` is identical on
-every rank and every combine DMA is issued unconditionally (dummy tiles go
-to the trash row), so the fused schedule still discharges under the legacy
-0.4.x interpreter's lockstep rule.
+every rank; in the padded schedule every combine DMA is issued (dummy
+tiles go to the trash row).
 
-**Dummy elision (real hardware):** the lockstep permutation padding exists
-only for the legacy interpreter's discharge rule. With ``elide_dummy``
-(default whenever the kernel is *not* interpreted) dummy-slot DMAs are
-predicated away with ``pl.when`` and receive waits count only the real
-blocks — the executed wire drops to :meth:`DispatchSchedule.issued_rounds`
-real rounds per direction.
+**Dummy elision:** with ``elide_dummy`` (default whenever the kernel is
+Mosaic-compiled) dummy-slot DMAs are predicated away with ``pl.when`` and
+receive waits count only the real blocks — the executed wire drops to
+:meth:`DispatchSchedule.issued_rounds` real rounds per direction.
+
+**Arrivals** are waited through a copy descriptor of the landed size
+(one microblock, or one combine block) on the per-source receive
+semaphore, so each tick is one landed block.
 
 Combine is the exact reverse schedule: rank ``e`` returns ``counts[e]``
 processed tokens to every source, shipped bf16/f32 (DeepSeek-V3 quantizes
@@ -67,14 +66,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import (interpret_params, shard_map, sync_copy,
-                          compiler_params as tpu_compiler_params)
+from jax import shard_map
+
+from repro.compat import compiler_params, default_interpret
 # The schedule machinery is defined once, in repro.core.schedule (the
 # collective-schedule contract); re-exported here for the kernel's callers.
 from repro.core.schedule import (DispatchSchedule, SendWindow,  # noqa: F401
                                  block_counts, make_schedule,
-                                 sanitize_combine_tile, sem_slot,
-                                 send_window_depths)
+                                 sanitize_combine_tile, send_window_depths)
 
 
 # ------------------------------------------------------------------- kernel
@@ -102,15 +101,15 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         # s2) and output ys ride along, and the shared FFN is issued
         # against the open dispatch send window (see run_rounds)
         (x_ref, w1_ref, w2_ref, xs_ref, s1_ref, s2_ref, y_ref, ys_ref,
-         xbuf, w1buf, w2buf, xsbuf, s1buf, s2buf,
+         xbuf, w1buf, w2buf, ybuf, xsbuf, s1buf, s2buf, ysbuf,
          send_q, send_s, recv_q, recv_s, ffn_out, comb,
          dsend, drecv, qsend, qrecv, csend, crecv) = refs
     else:
         (x_ref, w1_ref, w2_ref, y_ref,
-         xbuf, w1buf, w2buf,
+         xbuf, w1buf, w2buf, ybuf,
          send_q, send_s, recv_q, recv_s, ffn_out, comb,
          dsend, drecv, qsend, qrecv, csend, crecv) = refs
-        xsbuf = s1buf = s2buf = ys_ref = None
+        xsbuf = s1buf = s2buf = ysbuf = ys_ref = None
     n, B = sched.n, sched.block_tokens
     b_max, blocks, counts = sched.b_max, sched.blocks, sched.counts
     stride = b_max * B                       # slab rows per edge region
@@ -118,63 +117,55 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
     d_model = x_ref.shape[1]
     me = jax.lax.axis_index(axis)
 
-    # GEMM operands live in ANY (HBM): stage them into VMEM before any
-    # compute touches them — the interpreter tolerates direct ANY reads
-    # but Mosaic on real TPU requires DMA-staged VMEM operands.
-    sync_copy(x_ref, xbuf)
-    sync_copy(w1_ref, w1buf)
-    sync_copy(w2_ref, w2buf)
+    # Operands and results live in ANY (HBM): Mosaic computes on VMEM
+    # only, so every operand is DMA-staged in and every result staged out.
+    pltpu.sync_copy(x_ref, xbuf)
+    pltpu.sync_copy(w1_ref, w1buf)
+    pltpu.sync_copy(w2_ref, w2buf)
     if shared:
-        sync_copy(xs_ref, xsbuf)
-        sync_copy(s1_ref, s1buf)
-        sync_copy(s2_ref, s2buf)
+        pltpu.sync_copy(xs_ref, xsbuf)
+        pltpu.sync_copy(s1_ref, s1buf)
+        pltpu.sync_copy(s2_ref, s2buf)
+
     def _lookup(table, idx):
         # static-table lookup by traced index without capturing a constant
-        # array (the legacy pallas tracer rejects non-scalar kernel consts)
+        # array (a Pallas kernel cannot close over non-scalar constants)
         out = jnp.int32(table[0])
         for k in range(1, n):
             out = jnp.where(idx == k, jnp.int32(table[k]), out)
         return out
 
-    # ---- stage: per-expert token blocks, B-quantized regions, wire dtype
-    x = xbuf[...]
-    parts = []
+    # ---- stage: per-expert token blocks into B-quantized send regions.
+    # Static ref slices (offsets/counts are trace-time ints). Rows past
+    # counts[e] in a region ship unwritten: the expert masks every row
+    # past its token count, and assembly reads only counts[e] rows back.
     for e in range(n):
         if counts[e] == 0:
-            parts.append(jnp.zeros((stride, d_model), x.dtype))
             continue
-        blk = jax.lax.dynamic_slice_in_dim(x, offsets[e], counts[e])
-        parts.append(jnp.pad(blk, ((0, stride - counts[e]), (0, 0))))
-    staged = jnp.concatenate(parts)                    # (n*stride, d)
-    if wire_i8:
-        q, s = quant_i8(staged)
-        send_q[...] = q
-        send_s[...] = s
-    else:
-        send_q[...] = staged
-    recv_q[...] = jnp.zeros_like(recv_q)
-    if wire_i8:
-        recv_s[...] = jnp.ones_like(recv_s)
-    comb[...] = jnp.zeros_like(comb)
+        blk = xbuf[pl.ds(offsets[e], counts[e])]
+        rows = pl.ds(e * stride, counts[e])
+        if wire_i8:
+            q, s = quant_i8(blk.astype(jnp.float32))
+            send_q[rows] = q
+            send_s[rows] = s
+        else:
+            send_q[rows] = blk.astype(send_q.dtype)
 
     # ---- round helpers -------------------------------------------------
-    def _dma(src_slab, dst_slab, ssems, rsems, src_off, dst_off, peer,
-             src_rank, rows):
+    # Receive semaphores are (sender, microblock) slots: the sender's
+    # descriptor names slot [me, j] on the receiver, so each landed block
+    # ticks its own slot whatever order the DMA engines complete in.
+    def _dma(src_slab, dst_slab, ssems, rsems, src_off, dst_off, peer, j,
+             rows):
         return pltpu.make_async_remote_copy(
             src_ref=src_slab.at[pl.ds(src_off, rows)],
             dst_ref=dst_slab.at[pl.ds(dst_off, rows)],
-            send_sem=ssems.at[peer], recv_sem=rsems.at[src_rank],
+            send_sem=ssems.at[peer], recv_sem=rsems.at[me, j],
             device_id=peer, device_id_type=pltpu.DeviceIdType.MESH)
 
-    # Receive-slot convention routed through the shared contract helper
-    # (core/schedule.py::sem_slot): slot s = edge from source rank s,
-    # under either the legacy lockstep or the sender-driven engine.
-    def _sem_slot(inbound_src):
-        return sem_slot(me, inbound_src)
-
-    # With elide_dummy (real hardware — lockstep issue not required) dummy
-    # rounds are predicated away entirely: start and wait_send both sit
-    # under the same pl.when so the send semaphore stays balanced.
+    # With elide_dummy (the compiled kernel) dummy rounds are predicated
+    # away entirely: start and wait_send both sit under the same pl.when
+    # so the send semaphore stays balanced.
     def _start(real, cps):
         def go():
             for cp in cps:
@@ -196,12 +187,10 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         real = j < _lookup(blocks, e)
         src_off = jnp.where(real, e * stride + j * B, 0)
         dst_off = jnp.where(real, me * stride + j * B, trash)
-        slot = _sem_slot(src)
-        cps = [_dma(send_q, recv_q, dsend, drecv, src_off, dst_off, e,
-                    slot, B)]
+        cps = [_dma(send_q, recv_q, dsend, drecv, src_off, dst_off, e, j, B)]
         if wire_i8:
             cps.append(_dma(send_s, recv_s, qsend, qrecv,
-                            src_off, dst_off, e, slot, B))
+                            src_off, dst_off, e, j, B))
         return real, cps
 
     def combine_round(off, j, t=0, rows=None):
@@ -209,13 +198,11 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         tile-fused path calls this per ``rows``-row sub-tile ``t``."""
         rows = B if rows is None else rows
         q = jax.lax.rem(me + off, n)                   # my receiver (source)
-        src = jax.lax.rem(me - off + n, n)             # my sender (expert)
         real = j < _lookup(blocks, me)                 # I own expert `me`
         rel = j * B + t * rows
         src_off = jnp.where(real, q * stride + rel, 0)
         dst_off = jnp.where(real, me * stride + rel, trash)
-        cp = _dma(ffn_out, comb, csend, crecv, src_off, dst_off, q,
-                  _sem_slot(src), rows)
+        cp = _dma(ffn_out, comb, csend, crecv, src_off, dst_off, q, j, rows)
         return real, [cp]
 
     def make_window():
@@ -251,13 +238,30 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
             probe.mark("shared_ffn")
         ys = swiglu_ffn(xsbuf[...].astype(jnp.float32),
                         s1buf[...], s2buf[...])
-        ys_ref.at[pl.ds(0, xsbuf.shape[0])][...] = ys.astype(ys_ref.dtype)
+        ysbuf[...] = ys.astype(ysbuf.dtype)
+        pltpu.sync_copy(ysbuf, ys_ref)
 
-    blk_elems = B * d_model                            # recv-sem units/block
-    scl_elems = B                                      # scale-sem units/block
+    def wait_block(rsems, slab, src, j):
+        """Tick microblock ``j`` from ``src``: a copy descriptor of the
+        block's size waits its receive slot."""
+        blk = slab.at[pl.ds(src * stride + j * B, B)]
+        pltpu.make_async_copy(blk, blk, rsems.at[src, j]).wait()
 
-    def wait_recv_edge(rsems, src, nblocks, elems):
-        pltpu.semaphore_wait(rsems.at[src], nblocks * elems)
+    def wait_dispatch(src, j):
+        wait_block(drecv, recv_q, src, j)
+        if wire_i8:
+            wait_block(qrecv, recv_s, src, j)
+
+    def wait_blocks(wait, src, lo, hi):
+        """Tick microblocks ``lo <= j < hi`` from ``src``; traced bounds
+        predicate one block-sized wait per schedule slot."""
+        for j in range(b_max):
+            live = (j >= lo) & (j < hi)
+            if isinstance(live, bool):
+                if live:
+                    wait(src, j)
+            else:
+                pl.when(live)(functools.partial(wait, src, j))
 
     def ffn_tile(src, rel, rows):
         """Expert FFN over ``rows`` landed tokens at region-relative offset
@@ -270,7 +274,7 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         h = swiglu_ffn(blk.astype(jnp.float32), w1buf[...], w2buf[...])
         valid = (rel + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
                  < _lookup(counts, me))
-        ffn_out.at[pl.ds(row0, rows)][...] = jnp.where(
+        ffn_out[pl.ds(row0, rows)] = jnp.where(
             valid, h, 0.0).astype(ffn_out.dtype)
 
     # real blocks on every inbound dispatch edge = my expert's block count
@@ -298,14 +302,11 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
 
                 # dummy rounds are never sent under elide_dummy, so the
                 # arrival wait is predicated away like every other elided op
-                def arrive(src=src):
-                    wait_recv_edge(drecv, src, 1, blk_elems)
-                    if wire_i8:
-                        wait_recv_edge(qrecv, src, 1, scl_elems)
+                arrive = functools.partial(wait_dispatch, src, j)
                 pl.when(real)(arrive) if elide_dummy else arrive()
                 for t in range(B // ct):
-                    # off-interpret, dummy tiles skip the GEMM too — their
-                    # combine DMA is elided, so nothing reads the output
+                    # with elide_dummy, dummy tiles skip the GEMM too —
+                    # their combine DMA is elided, so nothing reads them
                     def tile(rel=j * B + t * ct):
                         ffn_tile(src, rel, ct)
                     pl.when(real)(tile) if elide_dummy else tile()
@@ -316,10 +317,8 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         # (real + dummy blocks) before any expert compute starts.
         for s_idx in range(n):
             src = jax.lax.rem(me + s_idx, n)
-            nb = my_blocks if elide_dummy else b_max
-            wait_recv_edge(drecv, src, nb, blk_elems)
-            if wire_i8:
-                wait_recv_edge(qrecv, src, nb, scl_elems)
+            wait_blocks(wait_dispatch, src, 0,
+                        my_blocks if elide_dummy else b_max)
         for s_idx in range(n):
             ffn_tile(jax.lax.rem(me + s_idx, n), 0, stride)
     else:
@@ -329,32 +328,31 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         # and its FFN runs immediately, before later edges are fenced.
         for s_idx in range(n):
             src = jax.lax.rem(me + s_idx, n)
-            wait_recv_edge(drecv, src, my_blocks, blk_elems)
-            if wire_i8:
-                wait_recv_edge(qrecv, src, my_blocks, scl_elems)
+            wait_blocks(wait_dispatch, src, 0, my_blocks)
             ffn_tile(src, 0, stride)
         if not elide_dummy:
             # drain the dummy-block residue so every semaphore balances
             for s_idx in range(n):
                 src = jax.lax.rem(me + s_idx, n)
-                wait_recv_edge(drecv, src, b_max - my_blocks, blk_elems)
-                if wire_i8:
-                    wait_recv_edge(qrecv, src, b_max - my_blocks, scl_elems)
+                wait_blocks(wait_dispatch, src, my_blocks, b_max)
 
     # ---- combine (reverse path, full precision) ------------------------
     if not tile_fused:
         run_rounds(combine_round)
+    # (a combine block lands as B/combine_tile sub-tile DMAs on the tile-
+    # fused path; a block-sized wait ticks once all of them landed)
     for s_idx in range(n):
         src = jax.lax.rem(me + s_idx, n)
-        nb = _lookup(blocks, src) if elide_dummy else b_max
-        wait_recv_edge(crecv, src, nb, blk_elems)
+        wait_blocks(functools.partial(wait_block, crecv, comb), src, 0,
+                    _lookup(blocks, src) if elide_dummy else b_max)
 
     # ---- assemble: region e holds my tokens processed by expert e ------
     for e in range(n):
         if counts[e] == 0:
             continue
-        y_ref.at[pl.ds(offsets[e], counts[e])][...] = \
-            comb[pl.ds(e * stride, counts[e])].astype(y_ref.dtype)
+        ybuf[pl.ds(offsets[e], counts[e])] = \
+            comb[pl.ds(e * stride, counts[e])].astype(ybuf.dtype)
+    pltpu.sync_copy(ybuf, y_ref)
 
 
 def moe_dispatch_combine_sharded(x, w1, w2, *, axis, sched: DispatchSchedule,
@@ -370,7 +368,8 @@ def moe_dispatch_combine_sharded(x, w1, w2, *, axis, sched: DispatchSchedule,
     local tokens, s1 (d, 2fs) / s2 (fs, d) the replicated shared-expert
     weights. The shared FFN is issued inside the kernel against the open
     dispatch send window and the call returns ``(y, ys)``. ``probe`` (a
-    :class:`~repro.core.trace.ScheduleProbe`) records interleave marks."""
+    :class:`~repro.core.trace.ScheduleProbe`) records interleave marks.
+    ``interpret=None`` picks :func:`repro.compat.default_interpret`."""
     T, d = x.shape
     n, B, b_max = sched.n, sched.block_tokens, sched.b_max
     assert sum(sched.counts) == T, (sched.counts, T)
@@ -382,10 +381,9 @@ def moe_dispatch_combine_sharded(x, w1, w2, *, axis, sched: DispatchSchedule,
     stride = b_max * B
     slab = n * stride + B                             # + trash block
     wire_dt = jnp.int8 if wire_i8 else x.dtype
-    ip = interpret if interpret is not None else interpret_params()
+    ip = default_interpret() if interpret is None else interpret
     if elide_dummy is None:
-        # the lockstep permutation padding is only needed by the
-        # interpreter's discharge rule; compiled TPU builds skip it
+        # the compiled kernel skips the padded schedule's dummy rounds
         elide_dummy = not ip
     kern = functools.partial(
         _moe_kernel, axis=axis, sched=sched, offsets=offsets,
@@ -400,6 +398,7 @@ def moe_dispatch_combine_sharded(x, w1, w2, *, axis, sched: DispatchSchedule,
         pltpu.VMEM((T, d), x.dtype),                    # staged x operand
         pltpu.VMEM(w1.shape, w1.dtype),                 # staged w1 operand
         pltpu.VMEM(w2.shape, w2.dtype),                 # staged w2 operand
+        pltpu.VMEM((T, d), x.dtype),                    # staged y result
     ]
     if shared is not None:
         xs, s1, s2 = shared
@@ -411,6 +410,7 @@ def moe_dispatch_combine_sharded(x, w1, w2, *, axis, sched: DispatchSchedule,
             pltpu.VMEM(xs.shape, xs.dtype),             # staged shared x
             pltpu.VMEM(s1.shape, s1.dtype),             # staged shared w1
             pltpu.VMEM(s2.shape, s2.dtype),             # staged shared w2
+            pltpu.VMEM(xs.shape, x.dtype),              # staged ys result
         ]
     return pl.pallas_call(
         kern,
@@ -425,14 +425,14 @@ def moe_dispatch_combine_sharded(x, w1, w2, *, axis, sched: DispatchSchedule,
             pltpu.VMEM((n * stride, d), jnp.float32),   # expert FFN out
             pltpu.VMEM((slab, d), jnp.float32),         # combine slab
             pltpu.SemaphoreType.DMA((n,)),              # dispatch send
-            pltpu.SemaphoreType.DMA((n,)),              # dispatch recv
+            pltpu.SemaphoreType.DMA((n, b_max)),        # dispatch recv
             pltpu.SemaphoreType.DMA((n,)),              # scale send
-            pltpu.SemaphoreType.DMA((n,)),              # scale recv
+            pltpu.SemaphoreType.DMA((n, b_max)),        # scale recv
             pltpu.SemaphoreType.DMA((n,)),              # combine send
-            pltpu.SemaphoreType.DMA((n,)),              # combine recv
+            pltpu.SemaphoreType.DMA((n, b_max)),        # combine recv
         ],
         interpret=ip,
-        compiler_params=tpu_compiler_params(collective_id=17),
+        compiler_params=compiler_params(),
     )(*inputs)
 
 
@@ -440,7 +440,8 @@ def moe_dispatch_combine(x, w1, w2, mesh, *, axis="x", counts,
                          block_tokens=64, tight=True, pipelined=True,
                          barrier=False, contexts=2, wire_i8=False,
                          tile_fused=False, combine_tile=None,
-                         elide_dummy=None, shared=None, probe=None):
+                         elide_dummy=None, shared=None, probe=None,
+                         interpret=None):
     """Global entry. x: (n, T, d) token-sharded over ``axis`` (each rank's
     rows sorted into contiguous per-expert blocks, identical static
     ``counts`` on every rank); w1: (n, d, 2f), w2: (n, f, d) — expert e's
@@ -451,7 +452,7 @@ def moe_dispatch_combine(x, w1, w2, mesh, *, axis="x", counts,
     s2 (fs, d) replicated shared-expert weights — returns ``(y, ys)``
     with ys (n, Ts, d) the shared-expert stream computed inside the
     kernel against the dispatch send window (the TokenWeave two-stream
-    serving point)."""
+    serving point). ``interpret`` as in :func:`moe_dispatch_combine_sharded`."""
     from jax.sharding import PartitionSpec as P
     sched = make_schedule(counts, block_tokens, tight)
 
@@ -465,7 +466,7 @@ def moe_dispatch_combine(x, w1, w2, mesh, *, axis="x", counts,
                 pipelined=pipelined, barrier=barrier, contexts=contexts,
                 wire_i8=wire_i8, tile_fused=tile_fused,
                 combine_tile=combine_tile, elide_dummy=elide_dummy,
-                probe=probe)
+                probe=probe, interpret=interpret)
             return out[None]
 
         return run(x, w1, w2)
@@ -482,7 +483,7 @@ def moe_dispatch_combine(x, w1, w2, mesh, *, axis="x", counts,
             pipelined=pipelined, barrier=barrier, contexts=contexts,
             wire_i8=wire_i8, tile_fused=tile_fused,
             combine_tile=combine_tile, elide_dummy=elide_dummy,
-            shared=(xss[0], s1r, s2r), probe=probe)
+            shared=(xss[0], s1r, s2r), probe=probe, interpret=interpret)
         return y[None], ys[None]
 
     return run2(x, w1, w2, xs, s1, s2)

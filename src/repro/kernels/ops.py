@@ -13,7 +13,7 @@ from repro.kernels.kv_shuttle import kv_shuttle as _kv
 
 @partial(jax.jit, static_argnames=("causal", "q_block", "kv_block", "interpret"))
 def flash_attention(q, k, v, *, causal=True, q_block=128, kv_block=128,
-                    interpret=True):
+                    interpret=None):
     return _fa(q, k, v, causal=causal, q_block=q_block, kv_block=kv_block,
                interpret=interpret)
 

@@ -5,12 +5,12 @@ collective-schedule contract (``repro.core.schedule.RingSchedule``).
 
 Each device owns one Q shard; KV shards rotate around the ring INSIDE the
 kernel via ``pltpu.make_async_remote_copy`` (the GIN-put analogue). The
-kernel is a full trace-time unroll of the schedule's lockstep
-``(step, chunk)`` rounds — in rotation step ``s`` every rank ships the KV
-shard it currently holds one hop forward (rank ``r`` → ``(r+1) % n``, a
-shift permutation the legacy 0.4.x interpreter discharges in lockstep),
+kernel is a full trace-time unroll of the schedule's ``(step, chunk)``
+rounds — in rotation step ``s`` every rank ships the KV shard it currently
+holds one hop forward (rank ``r`` → ``(r+1) % n``, a shift permutation),
 split into ``kv_chunk``-row chunks staged in chunk-major VMEM double
-buffers.
+buffers. Arrivals are waited through a copy descriptor of the landed
+chunk's size on that chunk's receive semaphore.
 
 Placement realizations (design-space P), all driven by the one schedule:
 
@@ -31,11 +31,18 @@ Placement realizations (design-space P), all driven by the one schedule:
 
 Slot-reuse backpressure: step ``s``'s send writes the neighbour slot its
 step ``s-1`` compute read — the sender waits the downstream free-slot
-credit before issuing (``remote_semaphore_signal`` ACK after the consumer
-drains; degenerates to local bookkeeping under the legacy interpreter).
+credit before issuing (a remote ``semaphore_signal`` ACK after the
+consumer drains).
 
-Every DMA is issued unconditionally in the schedule's total order (the
-lockstep discharge rule); no ``pl.when`` wraps any ``dma.start()``.
+Every DMA is issued unconditionally in the schedule's total order; no
+``pl.when`` wraps any ``dma.start()``.
+
+Lane packing: Mosaic slices and ships refs only in whole 128-lane rows, so
+heads narrower than 128 (``hd`` = 64 in the workload) travel and compute
+packed ``128 // hd`` to a row. Head ``j`` of a row owns lanes
+``[j*hd, (j+1)*hd)``; its scores contract a copy of Q with the other
+heads' lanes zeroed, and its P·V keeps only its own lanes. The wire
+carries exactly the heads' bytes.
 """
 from __future__ import annotations
 
@@ -46,37 +53,68 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import (interpret_params, remote_semaphore_signal,
-                          shard_map, sync_copy,
-                          compiler_params as tpu_compiler_params)
+from jax import shard_map
+
+from repro.compat import compiler_params, default_interpret
 from repro.core.schedule import (RingSchedule, SendWindow,  # noqa: F401
                                  make_ring_schedule, sanitize_kv_chunk)
 
 NEG_INF = -1e30
+LANES = 128
+
+
+def heads_per_row(BH, hd):
+    """How many heads share one 128-lane row (1 when ``hd`` fills whole
+    rows, or when ``BH`` heads cannot be grouped evenly)."""
+    g = LANES // hd if hd < LANES and LANES % hd == 0 else 1
+    return g if BH % g == 0 else 1
+
+
+def _pack(x, g):
+    """(BH, S, hd) -> (BH/g, S, g*hd): heads 0..g-1 of a group side by
+    side in the lanes."""
+    BH, S, hd = x.shape
+    return x.reshape(BH // g, g, S, hd).swapaxes(1, 2).reshape(
+        BH // g, S, g * hd)
+
+
+def _unpack(x, g):
+    G, S, L = x.shape
+    return x.reshape(G, S, g, L // g).swapaxes(1, 2).reshape(G * g, S, L // g)
 
 
 def _ring_kernel(q_ref, k_ref, v_ref, o_ref, kbuf, vbuf,
                  ksend, krecv, vsend, vrecv, credit,
                  *, axis, sched: RingSchedule, causal, scale, counter,
-                 pipelined, eager_wait, contexts):
+                 pipelined, eager_wait, contexts, hd):
     n, nc, cr = sched.n, sched.nc, sched.kv_chunk
     fused = sched.fused
-    BH, Sl, hd = q_ref.shape
+    G, Sl, L = q_ref.shape                   # head groups, rows, lanes
+    g = L // hd                              # heads packed per row
     me = jax.lax.axis_index(axis)
     nxt = jax.lax.rem(me + 1, n)
     prv = jax.lax.rem(me - 1 + n, n)
-    chunk_elems = BH * cr * hd
 
     # local KV shard -> double-buffer slot 0 (k_ref/v_ref arrive chunk-major
-    # (nc, BH, cr, hd) from the sharded entry; kbuf rows [slot*nc + c])
+    # (nc, G, cr, L) from the sharded entry; kbuf rows [slot*nc + c])
     for c in range(nc):
-        sync_copy(k_ref.at[c], kbuf.at[c])
-        sync_copy(v_ref.at[c], vbuf.at[c])
+        pltpu.sync_copy(k_ref.at[c], kbuf.at[c])
+        pltpu.sync_copy(v_ref.at[c], vbuf.at[c])
 
-    q = q_ref[...].astype(jnp.float32)                 # (BH, Sl, hd)
-    acc = jnp.zeros((BH, Sl, hd), jnp.float32)
-    m_i = jnp.full((BH, Sl), NEG_INF, jnp.float32)
-    l_i = jnp.zeros((BH, Sl), jnp.float32)
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, 1, L), 2) // hd
+    own = [lane_head == j for j in range(g)]           # head j's lanes
+    q = q_ref[...].astype(jnp.float32)                 # (G, Sl, L)
+    qs = [q] if g == 1 else [jnp.where(own[j], q, 0.0) for j in range(g)]
+    acc = jnp.zeros((G, Sl, L), jnp.float32)
+    m_i = [jnp.full((G, Sl), NEG_INF, jnp.float32)] * g
+    l_i = [jnp.zeros((G, Sl), jnp.float32)] * g
+
+    def on_lanes(per_head):
+        """Spread per-head (G, Sl) values over their heads' lanes."""
+        if g == 1:
+            return per_head[0][:, :, None]
+        return sum(jnp.where(own[j], per_head[j][:, :, None], 0.0)
+                   for j in range(g))
 
     def chunk_dma(buf, ssem, rsem_slot, src_chunk, dst_chunk, nchunks):
         """Ship kbuf/vbuf chunks [src_chunk, src_chunk+nchunks) one hop
@@ -89,48 +127,61 @@ def _ring_kernel(q_ref, k_ref, v_ref, o_ref, kbuf, vbuf,
 
     # contexts-deep send window over the trace-time round order (the shared
     # schedule.SendWindow — a round's K/V pair counts as ONE entry): every
-    # DMA is issued unconditionally (lockstep rule), the window only bounds
+    # DMA is issued unconditionally, the window only bounds
     # how many rounds' send semaphores stay unawaited. Drained at each step
     # boundary (the slot-credit handshake needs the step's sends retired).
     window = SendWindow(contexts)
 
+    # Receive semaphores are (landing slot, chunk) pairs: once this rank
+    # ACKs a slot free, upstream's next send into the *other* slot may be
+    # in flight before this rank ticks the current one, and a shared
+    # semaphore could not tell the two arrivals apart.
     def issue(slot, c, nchunks):
-        kd = chunk_dma(kbuf, ksend, krecv.at[c], slot * nc + c,
+        kd = chunk_dma(kbuf, ksend, krecv.at[1 - slot, c], slot * nc + c,
                        (1 - slot) * nc + c, nchunks)
-        vd = chunk_dma(vbuf, vsend, vrecv.at[c], slot * nc + c,
+        vd = chunk_dma(vbuf, vsend, vrecv.at[1 - slot, c], slot * nc + c,
                        (1 - slot) * nc + c, nchunks)
         window.push([kd, vd])
 
-    def tick(c, nchunks):
-        """Receive-side readiness: chunk c of the in-flight rotation
-        landed (COUNTER consumes these one chunk at a time)."""
-        pltpu.semaphore_wait(krecv.at[c], nchunks * chunk_elems)
-        pltpu.semaphore_wait(vrecv.at[c], nchunks * chunk_elems)
+    def tick(slot, c, nchunks):
+        """Receive-side readiness: chunks [c, c+nchunks) landed in
+        ``slot`` (COUNTER consumes these one chunk at a time)."""
+        for buf, rsem in ((kbuf, krecv), (vbuf, vrecv)):
+            landed = buf.at[pl.ds(slot * nc + c, nchunks)]
+            pltpu.make_async_copy(landed, landed, rsem.at[slot, c]).wait()
 
     def attend(s, c, acc, m_i, l_i):
         """Flash-accumulate the attention contribution of chunk ``c`` of
         the shard held at step ``s`` (originating rank (me - s) % n)."""
         slot = s % 2
-        k_c = kbuf[slot * nc + c].astype(jnp.float32)  # (BH, cr, hd)
+        k_c = kbuf[slot * nc + c].astype(jnp.float32)  # (G, cr, L)
         v_c = vbuf[slot * nc + c].astype(jnp.float32)
-        s_mat = jax.lax.dot_general(
-            q, k_c, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # (BH, Sl, cr)
         if causal:
             src_dev = jax.lax.rem(me - s + n, n)
-            qpos = me * Sl + jax.lax.broadcasted_iota(
-                jnp.int32, s_mat.shape, 1)
+            shape = (G, Sl, cr)
+            qpos = me * Sl + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
             kpos = src_dev * Sl + c * cr + jax.lax.broadcasted_iota(
-                jnp.int32, s_mat.shape, 2)
-            s_mat = jnp.where(qpos >= kpos, s_mat, NEG_INF)
-        m_new = jnp.maximum(m_i, jnp.max(s_mat, axis=2))
-        alpha = jnp.exp(m_i - m_new)
-        p = jnp.exp(s_mat - m_new[:, :, None])
-        l_i = l_i * alpha + jnp.sum(p, axis=2)
-        acc = acc * alpha[:, :, None] + jax.lax.dot_general(
-            p, v_c, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l_i
+                jnp.int32, shape, 2)
+            visible = qpos >= kpos
+        alphas, pvs, m_new, l_new = [], [], [], []
+        for j in range(g):
+            s_mat = jax.lax.dot_general(
+                qs[j], k_c, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale  # (G, Sl, cr)
+            if causal:
+                s_mat = jnp.where(visible, s_mat, NEG_INF)
+            m_j = jnp.maximum(m_i[j], jnp.max(s_mat, axis=2))
+            alpha = jnp.exp(m_i[j] - m_j)
+            p = jnp.exp(s_mat - m_j[:, :, None])
+            l_new.append(l_i[j] * alpha + jnp.sum(p, axis=2))
+            m_new.append(m_j)
+            alphas.append(alpha)
+            pv = jax.lax.dot_general(
+                p, v_c, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)       # (G, Sl, L)
+            pvs.append(pv if g == 1 else jnp.where(own[j], pv, 0.0))
+        acc = acc * on_lanes(alphas) + sum(pvs)
+        return acc, m_new, l_new
 
     for s in range(n):                       # n compute rounds, n-1 rotations
         slot = s % 2
@@ -143,10 +194,10 @@ def _ring_kernel(q_ref, k_ref, v_ref, o_ref, kbuf, vbuf,
             if not counter and s >= 1:
                 # SIGNAL: drain the whole step's arrivals up front
                 for c in range(nc):
-                    tick(c, 1)
+                    tick(slot, c, 1)
             for c in range(nc):
                 if counter and s >= 1:
-                    tick(c, 1)               # consume chunk c's arrival ...
+                    tick(slot, c, 1)         # consume chunk c's arrival ...
                 if rotate:
                     issue(slot, c, 1)        # ... ship it onward (windowed)
                 acc, m_i, l_i = attend(s, c, acc, m_i, l_i)
@@ -156,19 +207,19 @@ def _ring_kernel(q_ref, k_ref, v_ref, o_ref, kbuf, vbuf,
                 issue(slot, 0, nc)           # one whole-shard round
                 if eager_wait or not pipelined:
                     window.drain()           # DEFERRED/ACQREL: fully fenced
-                    tick(0, nc)
+                    tick(1 - slot, 0, nc)
             for c in range(nc):
                 acc, m_i, l_i = attend(s, c, acc, m_i, l_i)
             if rotate and pipelined and not eager_wait:
                 window.drain()           # lazy fence: after the compute
-                tick(0, nc)
+                tick(1 - slot, 0, nc)
         if s <= n - 3:
             # slot s%2 fully consumed (compute done, outgoing sends
             # retired): upstream's next-next send may reuse it
-            remote_semaphore_signal(credit, 1, device_id=prv,
-                                    device_id_type=pltpu.DeviceIdType.MESH)
+            pltpu.semaphore_signal(credit, 1, device_id=prv,
+                                   device_id_type=pltpu.DeviceIdType.MESH)
 
-    o_ref[...] = (acc / jnp.maximum(l_i, 1e-30)[:, :, None]
+    o_ref[...] = (acc / jnp.maximum(on_lanes(l_i), 1e-30)
                   ).astype(o_ref.dtype)
 
 
@@ -185,36 +236,39 @@ def ring_attention_sharded(q, k, v, *, axis, n_dev, causal=True,
         sched = make_ring_schedule(n_dev, Sl, kv_chunk or Sl, fused)
     assert sched.n == n_dev and sched.rows == Sl, (sched, n_dev, Sl)
     nc, cr = sched.nc, sched.kv_chunk
+    g = heads_per_row(BH, hd)
+    G, L = BH // g, g * hd
+    q, k, v = (_pack(x, g) for x in (q, k, v))
     # chunk-major staging: the kernel's KV buffers (and rotation DMAs)
     # address whole chunks through a single leading index
-    kc = k.reshape(BH, nc, cr, hd).swapaxes(0, 1)
-    vc = v.reshape(BH, nc, cr, hd).swapaxes(0, 1)
+    kc = k.reshape(G, nc, cr, L).swapaxes(0, 1)
+    vc = v.reshape(G, nc, cr, L).swapaxes(0, 1)
     kern = functools.partial(_ring_kernel, axis=axis, sched=sched,
                              causal=causal, scale=scale, counter=counter,
                              pipelined=pipelined, eager_wait=eager_wait,
-                             contexts=contexts)
-    ip = interpret if interpret is not None else interpret_params()
-    return pl.pallas_call(
+                             contexts=contexts, hd=hd)
+    out = pl.pallas_call(
         kern,
         in_specs=[
-            pl.BlockSpec((BH, Sl, hd), lambda: (0, 0, 0)),  # q in VMEM
+            pl.BlockSpec((G, Sl, L), lambda: (0, 0, 0)),    # q in VMEM
             pl.BlockSpec(memory_space=pl.ANY),              # k chunks (HBM)
             pl.BlockSpec(memory_space=pl.ANY),              # v chunks (HBM)
         ],
-        out_specs=pl.BlockSpec((BH, Sl, hd), lambda: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sl, hd), q.dtype),
+        out_specs=pl.BlockSpec((G, Sl, L), lambda: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((G, Sl, L), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((2 * nc, BH, cr, hd), q.dtype),  # K double buffer
-            pltpu.VMEM((2 * nc, BH, cr, hd), q.dtype),  # V double buffer
+            pltpu.VMEM((2 * nc, G, cr, L), q.dtype),    # K double buffer
+            pltpu.VMEM((2 * nc, G, cr, L), q.dtype),    # V double buffer
             pltpu.SemaphoreType.DMA,                    # k send
-            pltpu.SemaphoreType.DMA((nc,)),             # k per-chunk recv
+            pltpu.SemaphoreType.DMA((2, nc)),           # k (slot, chunk) recv
             pltpu.SemaphoreType.DMA,                    # v send
-            pltpu.SemaphoreType.DMA((nc,)),             # v per-chunk recv
+            pltpu.SemaphoreType.DMA((2, nc)),           # v (slot, chunk) recv
             pltpu.SemaphoreType.REGULAR,                # free-slot credit
         ],
-        interpret=ip,
-        compiler_params=tpu_compiler_params(collective_id=7),
+        interpret=default_interpret() if interpret is None else interpret,
+        compiler_params=compiler_params(),
     )(q, kc, vc)
+    return _unpack(out, g)
 
 
 def ring_attention(q, k, v, mesh, *, axis="x", causal=True, kv_chunk=None,

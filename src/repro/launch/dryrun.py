@@ -1,10 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 derive the roofline terms from the compiled artifact.
 
-The two lines above MUST stay the first statements in this module — jax locks
+CPU-only: it forces 512 placeholder host devices and ``--all`` fans cells
+out to subprocesses, so it cannot share a TPU (a chip belongs to one
+process at a time). On the chip, use ``chip_smoke.py``.
+
+The lines above MUST stay the first statements in this module — jax locks
 the device count on first init, and the production meshes need 512 placeholder
 devices. Do not set this flag globally; smoke tests and benches see 1 device.
 
@@ -165,6 +170,8 @@ def main():
     ap.add_argument("--sp-residuals", action="store_true")
     ap.add_argument("--loss-chunk", type=int, default=512)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     ARTIFACTS.mkdir(parents=True, exist_ok=True)
     if args.all:

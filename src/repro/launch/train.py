@@ -34,6 +34,8 @@ def main():
     ap.add_argument("--sp-residuals", action="store_true")
     ap.add_argument("--loss-chunk", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     production = n_dev >= 256 and not args.smoke

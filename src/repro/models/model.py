@@ -23,7 +23,7 @@ from repro.models.transformer import (attn_block_apply, attn_block_init,
 from repro.models.xlstm import (mlstm_apply, mlstm_init, mlstm_init_state,
                                 mlstm_state_shape, slstm_apply, slstm_init,
                                 slstm_init_state, slstm_state_shape)
-from repro.compat import shard_map
+from jax import shard_map
 
 F32 = jnp.float32
 MAX_LEARNED_POS = 32768
@@ -75,9 +75,11 @@ def init_params(key, cfg):
     unit, R = cfg.repeat_unit, cfg.num_repeats
 
     def stack_slot(slot):
+        # one vmapped init per slot: the same values as R separate inits
+        # stacked, in one small program (R unrolled inits take minutes to
+        # compile at full width, and stacking them holds two copies)
         ks = jax.random.split(jax.random.fold_in(keys[2], slot), R)
-        leaves = [_block_init(k, cfg, slot, dtype) for k in ks]
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *leaves)
+        return jax.vmap(lambda k: _block_init(k, cfg, slot, dtype))(ks)
 
     params["blocks"] = {f"s{i}": stack_slot(i) for i in range(unit)}
     params["final_norm"] = norm_init(d, cfg.norm, dtype)

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import dense_init
-from repro.compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 
 F32 = jnp.float32
 

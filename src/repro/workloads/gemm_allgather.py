@@ -36,7 +36,7 @@ from repro.kernels.gemm_allgather import (gemm_allgather as ga_kernel,
 from repro.workloads.base import (BARRIER_OVERHEAD, KERNEL_LAUNCH,
                                   SIGNAL_OVERHEAD, TILE_SYNC, Workload,
                                   register)
-from repro.compat import shard_map
+from jax import shard_map
 
 
 @register

@@ -35,7 +35,7 @@ from repro.core.schedule import make_ring_schedule
 from repro.kernels.kv_shuttle import kv_shuttle as shuttle_kernel
 from repro.workloads.base import (KERNEL_LAUNCH, SIGNAL_OVERHEAD, TILE_SYNC,
                                   BARRIER_OVERHEAD, Workload, register)
-from repro.compat import shard_map
+from jax import shard_map
 
 
 @register

@@ -55,7 +55,7 @@ from repro.core.design_space import Directive
 from repro.workloads.base import (BARRIER_OVERHEAD, KERNEL_LAUNCH,
                                   SIGNAL_OVERHEAD, TILE_SYNC, Workload,
                                   register)
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.cost_model import (CostBreakdown, CostSegment,
                                    per_tile_exposed_s, window_stall_factor)
 from repro.kernels.moe_dispatch import make_schedule, quant_i8, swiglu_ffn
@@ -298,8 +298,8 @@ class MoEDispatch(Workload):
             # quantize/dispatch/compute/combine chain; per-edge signal
             # semaphores instead of a global barrier; per-round DMA
             # issue/check overhead for the permutation schedule. The l3
-            # target is real TPU hardware, where the interpreter's lockstep
-            # dummy rounds are elided — charge the tighter executed
+            # target is real TPU hardware, where the compiled kernel elides
+            # the padded schedule's dummy rounds — charge the tighter executed
             # schedule, never the padded one.
             B = k["block_tokens"]
             sched = make_schedule(counts, B, k["tight"])

@@ -42,7 +42,7 @@ from repro.kernels.ring_attention import ring_attention as ring_kernel
 from repro.workloads.base import (BARRIER_OVERHEAD, KERNEL_LAUNCH,
                                   SIGNAL_OVERHEAD, TILE_SYNC, Workload,
                                   register)
-from repro.compat import shard_map
+from jax import shard_map
 
 
 @register

@@ -8,7 +8,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import compressed_psum, hierarchical_psum
 from repro.launch.mesh import make_mesh
-from repro.compat import shard_map
+from jax import shard_map
 
 mesh = make_mesh((2, 4), ("pod", "data"))
 key = jax.random.PRNGKey(0)

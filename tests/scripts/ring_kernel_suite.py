@@ -15,8 +15,7 @@ Covers the acceptance criteria that need devices:
   * race/deadlock freedom of the chunk-rotating path is proven by the
     static verifier (``core/verify.py`` — the same checker the cascade
     runs at l0, so there is exactly one race checker in the repo), and a
-    seeded premature-slot-reuse mutation is caught. Unlike the old
-    ``detect_races`` interpret hook this holds on legacy jax too.
+    seeded premature-slot-reuse mutation is caught.
 """
 import jax
 import jax.numpy as jnp

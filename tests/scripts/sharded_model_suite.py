@@ -9,7 +9,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_arch, reduced
 from repro.dist.sharding import Rules, sanitize_specs
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.launch.mesh import make_mesh
 from repro.models import (decode_step, init_params, param_specs,
                           prefill_step, train_loss)

@@ -12,6 +12,7 @@ Tier-1 coverage of the degraded-mode contract that needs no devices:
     never stall slow_path) and the one-retry-with-backoff l2 seam.
 """
 import math
+import threading
 import time
 
 import jax.numpy as jnp
@@ -206,6 +207,43 @@ def test_evaluator_quarantines_wedged_candidate(hw):
     ok = ev.evaluate(Candidate(directive=Directive(
         "PALLAS_RDMA", "SIGNAL", "DEFERRED")))
     assert ok.ok and not ok.quarantined
+
+
+def test_evaluator_wedged_l2_hands_the_slot_on(hw):
+    """A candidate that wedges inside its l2 execution holds the process's
+    one l2 slot. Quarantined at its deadline, it hands the slot on: the
+    next candidates, of this evaluator and of another, still reach l3, in
+    turn and in a batch, and the abandoned execution publishes nothing
+    when it comes back."""
+    mesh = make_mesh((1,), ("x",))
+    ev = CascadeEvaluator(ToyWorkload(), mesh, hw, timeout_s=2.0)
+    orig = ev._run_l2
+    wake = threading.Event()
+    wedged = []
+
+    def wedging(jfn):
+        if not wedged:
+            wedged.append(1)
+            wake.wait(60.0)              # wedges the execution, not the trace
+        return orig(jfn)
+
+    ev._run_l2 = wedging
+    try:
+        res = ev.evaluate(Candidate(directive=CONSERVATIVE))
+        assert res.quarantined and "at l2" in res.diagnostic
+        for _ in range(2):
+            ok = ev.evaluate(Candidate(directive=CONSERVATIVE))
+            assert ok.ok and not ok.quarantined
+        other = CascadeEvaluator(ToyWorkload(), mesh, hw, timeout_s=2.0)
+        batch = other.evaluate_batch(
+            [Candidate(directive=CONSERVATIVE) for _ in range(3)],
+            max_workers=3)
+        assert all(r.ok and not r.quarantined for r in batch)
+    finally:
+        wake.set()
+    time.sleep(0.5)                      # the abandoned thread finishes
+    assert [r.quarantined for r in ev.records] == [True, False, False]
+    assert len(ev.quarantine_report()) == 1
 
 
 def test_evaluator_retries_flaky_l2(hw):

@@ -1,151 +1,10 @@
-"""Multi-device suites (remote-DMA kernels, workload directive equivalence,
-sharded model paths, CUCo end-to-end). These need simulated host devices, and
-jax pins the device count at first init — so each suite runs in a subprocess
-with XLA_FLAGS set. The scripts live in tests/scripts/."""
-import os
-import pathlib
-import subprocess
-import sys
-
-
-SCRIPTS = pathlib.Path(__file__).parent / "scripts"
-SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
-
-
-def run_script(name, devices=4, timeout=1500, args=()):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run([sys.executable, str(SCRIPTS / name), *args],
-                          env=env,
-                          capture_output=True, text=True, timeout=timeout)
-    assert proc.returncode == 0, (
-        f"{name} failed\nSTDOUT:\n{proc.stdout[-4000:]}\n"
-        f"STDERR:\n{proc.stderr[-4000:]}")
-    return proc.stdout
-
-
-def test_ring_attention_kernel_sweep():
-    out = run_script("ring_kernel_suite.py")
-    assert "ALL OK" in out
-
-
-def test_collective_kernels():
-    out = run_script("collective_kernels_suite.py")
-    assert "ALL OK" in out
-
-
-def test_gemm_allgather_8rank():
-    """The executable counterpart of the fig6 sweep at a wider mesh
-    (ROADMAP open item): the collective suite's budget-capped path at 8
-    simulated ranks — FLUX + DEFERRED broadcast cascades to l3, fused and
-    deferred numerics vs the oracle."""
-    out = run_script("collective_kernels_suite.py", devices=8,
-                     args=["--n-dev", "8"])
-    assert "ALL OK" in out
-    assert "flux l3 ok at 8 ranks" in out
-
-
-def test_workload_directives_verify():
-    out = run_script("workload_suite.py")
-    assert "ALL OK" in out
-
-
-def test_moe_dispatch_deepep_kernel():
-    out = run_script("moe_dispatch_suite.py")
-    assert "ALL OK" in out
-
-
-def test_moe_dispatch_8rank():
-    """The executable counterpart of the fig4 --n-dev 8 analytic sweep
-    (ROADMAP open item): the suite's budget-capped path at 8 simulated
-    ranks — Table-3 validity, DeepEP + FLUX cascades to l3, kernel
-    numerics, tight-wire accounting."""
-    out = run_script("moe_dispatch_suite.py", devices=8,
-                     args=["--n-dev", "8"])
-    assert "ALL OK" in out
-    assert "flux l3 ok at 8 ranks" in out
-
-
-def test_fault_suite(tmp_path):
-    """Degraded-mode schedules under injected faults: every workload's
-    dropped-peer plan cascades to l3 on the surviving mesh, wire faults
-    are classified (not crashed on), a wedged candidate quarantines, and
-    the healthy-vs-degraded benchmark artifact is emitted."""
-    out_json = tmp_path / "BENCH_faults.json"
-    out = run_script("fault_suite.py", args=["--out", str(out_json)])
-    assert "ALL OK" in out
-    import json
-    bench = json.loads(out_json.read_text())
-    assert set(bench["workloads"]) == {"moe_dispatch", "ring_attention",
-                                       "gemm_allgather", "kv_transfer"}
-    for entry in bench["workloads"].values():
-        assert entry["degraded_ms"] > entry["healthy_ms"] > 0.0
-
-
-def test_telemetry_suite(tmp_path):
-    """Observability layer end to end: the short telemetry search, one
-    Perfetto timeline per workload (critical path == analytic_cost), the
-    observed-vs-modeled ScheduleProbe check — and the regenerated
-    BENCH_search.json must match the checked-in artifact byte for byte
-    (the search is deterministic; a diff means the search or its
-    telemetry changed and the artifact needs re-checking-in)."""
-    out_json = tmp_path / "BENCH_search.json"
-    out = run_script("telemetry_suite.py", args=["--out", str(out_json)])
-    assert "ALL OK" in out
-    import json
-    regen = json.loads(out_json.read_text())
-    assert regen["schema"] == "bench-search/v2"
-    checked_in = pathlib.Path(__file__).parents[1] / "BENCH_search.json"
-    assert json.loads(checked_in.read_text()) == regen, (
-        "regenerate with: XLA_FLAGS=--xla_force_host_platform_device_count=4 "
-        "PYTHONPATH=src python tests/scripts/telemetry_suite.py")
-
-
-def test_search_scale_suite(tmp_path):
-    """Scaled search end to end: batched ring_attention parity at 4 ranks,
-    gemm_allgather warm-start economics (cold best reached in <= half the
-    fresh evaluations), gemm_allgather -> moe_dispatch transfer seeding —
-    and the regenerated BENCH_search_scale.json must match the checked-in
-    artifact byte for byte (the searches are deterministic; a diff means
-    the search changed and the artifact needs re-checking-in)."""
-    out_json = tmp_path / "BENCH_search_scale.json"
-    out = run_script("search_scale_suite.py", args=["--out", str(out_json)])
-    assert "ALL OK" in out
-    import json
-    regen = json.loads(out_json.read_text())
-    assert regen["schema"] == "bench-search-scale/v1"
-    w = regen["warm_start"]
-    assert w["warm_fresh_evals_to_best"] <= w["cold_evals_to_best"] // 2
-    assert w["coverage_resumed"] >= w["coverage_saved"]
-    x = regen["transfer"]
-    assert x["transferred_seeds"] > 0
-    assert x["transfer_fresh_evals_to_best"] <= x["cold_evals_to_best"] // 2
-    checked_in = pathlib.Path(__file__).parents[1] / "BENCH_search_scale.json"
-    assert json.loads(checked_in.read_text()) == regen, (
-        "regenerate with: XLA_FLAGS=--xla_force_host_platform_device_count=4 "
-        "PYTHONPATH=src python tests/scripts/search_scale_suite.py")
-
-
-def test_serving_suite(tmp_path):
-    """Kernelized serving tier end to end: the serving_step overlap points
-    cascade to l3, the two-stream kernel issues the shared-expert FFN
-    inside the dispatch send window, the engine's pallas decode matches
-    host greedy tokens through continuous batching, the cache handoff
-    rides kv_shuttle, a mid-run rank drop keeps serving — and the
-    regenerated BENCH_serving.json must match the checked-in artifact
-    (the rows are modeled, hence deterministic; a diff means the cost
-    model changed and the artifact needs re-checking-in)."""
-    out_json = tmp_path / "BENCH_serving.json"
-    out = run_script("serving_suite.py", args=["--out", str(out_json)])
-    assert "ALL OK" in out
-    import json
-    regen = json.loads(out_json.read_text())
-    assert regen["schema"] == "bench-rows/v1"
-    checked_in = pathlib.Path(__file__).parents[1] / "BENCH_serving.json"
-    assert json.loads(checked_in.read_text()) == regen, (
-        "regenerate with: XLA_FLAGS=--xla_force_host_platform_device_count=4 "
-        "PYTHONPATH=src python tests/scripts/serving_suite.py")
+"""Multi-device suites: sharded model paths, collective helpers, the
+semantics-preserving schedule options and CUCo end to end. CPU-only: each
+suite runs in a subprocess on simulated host devices (tests/suite_runner.py).
+The remote-DMA kernel suites live in test_multidevice_kernels.py,
+test_multidevice_moe.py and test_multidevice_search.py, one file per
+pytest-xdist worker."""
+from suite_runner import run_script
 
 
 def test_sharded_model_equivalence():

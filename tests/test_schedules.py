@@ -4,11 +4,11 @@ in ``src/repro/core/schedule.py``: the moe_dispatch permutation-round
 schedule (``DispatchSchedule``), the gemm_allgather broadcast-round schedule
 (``BroadcastSchedule``), and the ring-rotation schedule (``RingSchedule``).
 
-Invariants (docs/kernels.md — the lockstep contract the legacy 0.4.x pallas
-interpreter enforces at runtime):
+Invariants (docs/kernels.md — the schedule contract every kernel issues
+its DMAs in):
   * every (edge, tile/microblock/chunk) event appears exactly once;
-  * the round order is total, deterministic, and rank-independent (lockstep:
-    every rank issues the same DMA sequence);
+  * the round order is total, deterministic, and rank-independent (every
+    rank walks the same round sequence);
   * the ``contexts``-deep send window never exceeds its cap and drains;
   * the sanitizers map any knob value to an exact divisor of the shape.
 """

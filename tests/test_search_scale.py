@@ -150,7 +150,10 @@ def test_batched_matches_sequential_mixed_generation(rig):
     rigged = _Rigged(wl)
     plan = FaultPlan("straggler", (FaultSpec(STRAGGLER, rank=0, rounds=4,
                                              delay_s=100e-6),))
-    mk = lambda: _BoundedEvaluator(rigged, mesh, hw, timeout_s=1.5,
+    # the wedge sleeps 60 s; the deadline leaves the real candidates room
+    # to queue behind each other's l2 (one at a time per process) on a
+    # loaded host
+    mk = lambda: _BoundedEvaluator(rigged, mesh, hw, timeout_s=6.0,
                                    fault_plans=(plan,), fault_weight=0.5)
     seed_d = CONSERVATIVE
     ev_seq, ev_bat = mk(), mk()
