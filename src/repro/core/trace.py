@@ -31,19 +31,33 @@ verifies it against the trace-time lockstep schedule — round order,
 window cap, and arrival count must match the ``CollectiveSchedule``
 contract the cost model charged.
 
-Pure trace-time Python (no jax imports), mirroring core/schedule.py.
+:func:`phase` marks a kernel's phases on the device's own clock: a Pallas
+TPU kernel body opens one region per phase (``jax.named_scope``, which
+Mosaic lowers to ``tpu.trace_start``/``trace_stop``), and the profiler
+records each region once the compile asks for custom-call region traces
+(:data:`REGION_TRACE_OPTIONS`). The regions exist only under the
+:func:`device_phases` switch, which is off by default and part of the jit
+key; off, a kernel lowers exactly as without the calls. The same call
+stamps an attached :class:`ScheduleProbe`'s marks.
+
+Trace-time Python, mirroring core/schedule.py; the device-phase switch is
+a JAX config state.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 from dataclasses import dataclass, field
+
+import jax
+from jax._src import config as jax_config
 
 from repro.core.cost_model import CostSegment
 from repro.core.faults import REMESH_OVERHEAD
 
 __all__ = [
     "TraceWriter", "Timeline", "ScheduleProbe", "schedule_timeline",
-    "validate_trace",
+    "validate_trace", "phase", "device_phases", "REGION_TRACE_OPTIONS",
 ]
 
 # thread ids of the per-rank track layout (one process per modeled rank)
@@ -379,3 +393,40 @@ class ScheduleProbe:
                 f"receive waits {n_recv} != completion_ticks {ticks}")
         return {"rounds": len(rounds), "max_depth": max(depths, default=0),
                 "recv_waits": n_recv}
+
+
+# ------------------------------------------------------- device phases
+
+# The compile option that makes the TPU compiler keep a kernel's
+# trace_start/trace_stop regions, so that the profiler records them.
+REGION_TRACE_OPTIONS = {"xla_enable_custom_call_region_trace": True}
+
+# In the jit key, so a step traced with regions is never served from a jit
+# entry traced without them, nor the reverse (JAX offers no public way to
+# add a config state to the key).
+_DEVICE_PHASES = jax_config.State(
+    "repro_device_phases", False,
+    "Open a named region for each phase of the device kernels "
+    "(repro.core.trace.phase).",
+    include_in_jit_key=True, include_in_trace_context=True)
+
+
+def device_phases(on=True):
+    """Context manager: kernels traced inside it open their phase regions
+    (``on``) or not. Read when a kernel body is traced, so lower and
+    compile inside it, with :data:`REGION_TRACE_OPTIONS`."""
+    return _DEVICE_PHASES(bool(on))
+
+
+@contextlib.contextmanager
+def phase(name, probe=None):
+    """One phase of a kernel body: stamps ``probe.mark(name)`` when a probe
+    is attached, and opens the device region ``name`` only while
+    :func:`device_phases` is on (otherwise nothing enters the trace)."""
+    if probe is not None:
+        probe.mark(name)
+    if not _DEVICE_PHASES.value:
+        yield
+        return
+    with jax.named_scope(name):
+        yield

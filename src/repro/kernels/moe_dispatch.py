@@ -74,6 +74,17 @@ from repro.compat import compiler_params, default_interpret
 from repro.core.schedule import (DispatchSchedule, SendWindow,  # noqa: F401
                                  block_counts, make_schedule,
                                  sanitize_combine_tile, send_window_depths)
+from repro.core.trace import phase
+
+# The kernel's device phases (repro.core.trace.phase), outermost first:
+# the whole call; the operands staged into VMEM; the dispatch rounds issued
+# and their send window drained; the expert FFN and the combine rounds,
+# which hold one ``arrival_wait`` per landed dispatch microblock and one
+# ``ffn`` per GEMM tile (per source off the tile-fused path); the combine
+# arrivals; the assembly of the outputs; the result staged out. The serving
+# layout adds ``shared_ffn`` inside ``dispatch``.
+PHASES = ("moe_dispatch", "stage_in", "dispatch", "ffn_combine",
+          "arrival_wait", "ffn", "combine_wait", "assemble", "stage_out")
 
 
 # ------------------------------------------------------------------- kernel
@@ -92,10 +103,16 @@ def swiglu_ffn(x, w1, w2):
     return (jax.nn.silu(g) * u) @ w2
 
 
-def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
-                barrier, contexts, wire_i8, tile_fused=False,
-                combine_tile=None, elide_dummy=False, shared=False,
-                probe=None):
+def _moe_kernel(*refs, **kw):
+    """The kernel body inside its ``moe_dispatch`` region (see PHASES)."""
+    with phase("moe_dispatch"):
+        _moe_body(*refs, **kw)
+
+
+def _moe_body(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
+              barrier, contexts, wire_i8, tile_fused=False,
+              combine_tile=None, elide_dummy=False, shared=False,
+              probe=None):
     if shared:
         # two-stream serving layout: the shared-expert operands (xs, s1,
         # s2) and output ys ride along, and the shared FFN is issued
@@ -119,13 +136,14 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
 
     # Operands and results live in ANY (HBM): Mosaic computes on VMEM
     # only, so every operand is DMA-staged in and every result staged out.
-    pltpu.sync_copy(x_ref, xbuf)
-    pltpu.sync_copy(w1_ref, w1buf)
-    pltpu.sync_copy(w2_ref, w2buf)
-    if shared:
-        pltpu.sync_copy(xs_ref, xsbuf)
-        pltpu.sync_copy(s1_ref, s1buf)
-        pltpu.sync_copy(s2_ref, s2buf)
+    with phase("stage_in"):
+        pltpu.sync_copy(x_ref, xbuf)
+        pltpu.sync_copy(w1_ref, w1buf)
+        pltpu.sync_copy(w2_ref, w2buf)
+        if shared:
+            pltpu.sync_copy(xs_ref, xsbuf)
+            pltpu.sync_copy(s1_ref, s1buf)
+            pltpu.sync_copy(s2_ref, s2buf)
 
     def _lookup(table, idx):
         # static-table lookup by traced index without capturing a constant
@@ -234,12 +252,11 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         local tokens, issued while dispatch DMAs are still in flight (the
         TokenWeave overlap — communication hidden behind compute the
         serving step has to do anyway)."""
-        if probe is not None:
-            probe.mark("shared_ffn")
-        ys = swiglu_ffn(xsbuf[...].astype(jnp.float32),
-                        s1buf[...], s2buf[...])
-        ysbuf[...] = ys.astype(ysbuf.dtype)
-        pltpu.sync_copy(ysbuf, ys_ref)
+        with phase("shared_ffn", probe):
+            ys = swiglu_ffn(xsbuf[...].astype(jnp.float32),
+                            s1buf[...], s2buf[...])
+            ysbuf[...] = ys.astype(ysbuf.dtype)
+            pltpu.sync_copy(ysbuf, ys_ref)
 
     def wait_block(rsems, slab, src, j):
         """Tick microblock ``j`` from ``src``: a copy descriptor of the
@@ -248,9 +265,10 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         pltpu.make_async_copy(blk, blk, rsems.at[src, j]).wait()
 
     def wait_dispatch(src, j):
-        wait_block(drecv, recv_q, src, j)
-        if wire_i8:
-            wait_block(qrecv, recv_s, src, j)
+        with phase("arrival_wait"):
+            wait_block(drecv, recv_q, src, j)
+            if wire_i8:
+                wait_block(qrecv, recv_s, src, j)
 
     def wait_blocks(wait, src, lo, hi):
         """Tick microblocks ``lo <= j < hi`` from ``src``; traced bounds
@@ -267,15 +285,16 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         """Expert FFN over ``rows`` landed tokens at region-relative offset
         ``rel`` of source region ``src`` (one GEMM tile of the fused loop;
         the per-source paths call it once with the whole region)."""
-        row0 = src * stride + rel
-        blk = recv_q[pl.ds(row0, rows)]
-        if wire_i8:
-            blk = blk.astype(jnp.float32) * recv_s[pl.ds(row0, rows)]
-        h = swiglu_ffn(blk.astype(jnp.float32), w1buf[...], w2buf[...])
-        valid = (rel + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-                 < _lookup(counts, me))
-        ffn_out[pl.ds(row0, rows)] = jnp.where(
-            valid, h, 0.0).astype(ffn_out.dtype)
+        with phase("ffn"):
+            row0 = src * stride + rel
+            blk = recv_q[pl.ds(row0, rows)]
+            if wire_i8:
+                blk = blk.astype(jnp.float32) * recv_s[pl.ds(row0, rows)]
+            h = swiglu_ffn(blk.astype(jnp.float32), w1buf[...], w2buf[...])
+            valid = (rel + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+                     < _lookup(counts, me))
+            ffn_out[pl.ds(row0, rows)] = jnp.where(
+                valid, h, 0.0).astype(ffn_out.dtype)
 
     # real blocks on every inbound dispatch edge = my expert's block count
     my_blocks = _lookup(blocks, me)
@@ -283,76 +302,86 @@ def _moe_kernel(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
     # ---- dispatch ------------------------------------------------------
     # (with `shared`, the shared-expert stream runs against the open
     # dispatch send window — before the drain, after the last issue)
-    run_rounds(dispatch_round, between=shared_compute if shared else None,
-               tag="dispatch")
+    with phase("dispatch"):
+        run_rounds(dispatch_round,
+                   between=shared_compute if shared else None, tag="dispatch")
 
-    if tile_fused:
-        # TILE_FUSED + COUNTER (the FLUX point): the expert FFN runs as a
-        # tiled GEMM loop and each output tile's combine DMA is issued the
-        # moment the tile is ready. Dispatch arrivals are consumed one
-        # microblock at a time (counter ticks on the edge semaphore), so
-        # the first tile computes while later peers are still in flight —
-        # and its combine write goes out before the next tile's GEMM.
-        ct = combine_tile          # sanitized by the sharded entry
-        window = make_window()
-        for off in range(n):
-            src = jax.lax.rem(me + off, n)             # source region
-            for j in range(b_max):
-                real = j < my_blocks
+    # ---- expert FFN and combine ----------------------------------------
+    with phase("ffn_combine"):
+        if tile_fused:
+            # TILE_FUSED + COUNTER (the FLUX point): the expert FFN runs
+            # as a tiled GEMM loop and each output tile's combine DMA is
+            # issued the moment the tile is ready. Dispatch arrivals are
+            # consumed one microblock at a time (counter ticks on the edge
+            # semaphore), so the first tile computes while later peers are
+            # still in flight — and its combine write goes out before the
+            # next tile's GEMM.
+            ct = combine_tile          # sanitized by the sharded entry
+            window = make_window()
+            for off in range(n):
+                src = jax.lax.rem(me + off, n)             # source region
+                for j in range(b_max):
+                    real = j < my_blocks
 
-                # dummy rounds are never sent under elide_dummy, so the
-                # arrival wait is predicated away like every other elided op
-                arrive = functools.partial(wait_dispatch, src, j)
-                pl.when(real)(arrive) if elide_dummy else arrive()
-                for t in range(B // ct):
-                    # with elide_dummy, dummy tiles skip the GEMM too —
-                    # their combine DMA is elided, so nothing reads them
-                    def tile(rel=j * B + t * ct):
-                        ffn_tile(src, rel, ct)
-                    pl.when(real)(tile) if elide_dummy else tile()
-                    window.push(combine_round(off, j, t, ct))
-        window.drain()
-    elif barrier or not pipelined:
-        # BARRIER / DEFERRED: global rendezvous — drain every edge fully
-        # (real + dummy blocks) before any expert compute starts.
-        for s_idx in range(n):
-            src = jax.lax.rem(me + s_idx, n)
-            wait_blocks(wait_dispatch, src, 0,
-                        my_blocks if elide_dummy else b_max)
-        for s_idx in range(n):
-            ffn_tile(jax.lax.rem(me + s_idx, n), 0, stride)
-    else:
-        # SIGNAL + TILE_PIPELINED: consume peers in arrival order — the
-        # self edge (s_idx 0) computes first, hiding later dispatch edges
-        # behind expert compute; each edge waits only its own semaphore,
-        # and its FFN runs immediately, before later edges are fenced.
-        for s_idx in range(n):
-            src = jax.lax.rem(me + s_idx, n)
-            wait_blocks(wait_dispatch, src, 0, my_blocks)
-            ffn_tile(src, 0, stride)
-        if not elide_dummy:
-            # drain the dummy-block residue so every semaphore balances
+                    # dummy rounds are never sent under elide_dummy, so
+                    # the arrival wait is predicated away like every other
+                    # elided op
+                    arrive = functools.partial(wait_dispatch, src, j)
+                    pl.when(real)(arrive) if elide_dummy else arrive()
+                    for t in range(B // ct):
+                        # with elide_dummy, dummy tiles skip the GEMM too —
+                        # their combine DMA is elided, so nothing reads them
+                        def tile(rel=j * B + t * ct):
+                            ffn_tile(src, rel, ct)
+                        pl.when(real)(tile) if elide_dummy else tile()
+                        window.push(combine_round(off, j, t, ct))
+            window.drain()
+        elif barrier or not pipelined:
+            # BARRIER / DEFERRED: global rendezvous — drain every edge
+            # fully (real + dummy blocks) before any expert compute starts.
             for s_idx in range(n):
                 src = jax.lax.rem(me + s_idx, n)
-                wait_blocks(wait_dispatch, src, my_blocks, b_max)
+                wait_blocks(wait_dispatch, src, 0,
+                            my_blocks if elide_dummy else b_max)
+            for s_idx in range(n):
+                ffn_tile(jax.lax.rem(me + s_idx, n), 0, stride)
+        else:
+            # SIGNAL + TILE_PIPELINED: consume peers in arrival order —
+            # the self edge (s_idx 0) computes first, hiding later dispatch
+            # edges behind expert compute; each edge waits only its own
+            # semaphore, and its FFN runs immediately, before later edges
+            # are fenced.
+            for s_idx in range(n):
+                src = jax.lax.rem(me + s_idx, n)
+                wait_blocks(wait_dispatch, src, 0, my_blocks)
+                ffn_tile(src, 0, stride)
+            if not elide_dummy:
+                # drain the dummy-block residue so every semaphore balances
+                for s_idx in range(n):
+                    src = jax.lax.rem(me + s_idx, n)
+                    wait_blocks(wait_dispatch, src, my_blocks, b_max)
 
-    # ---- combine (reverse path, full precision) ------------------------
-    if not tile_fused:
-        run_rounds(combine_round)
+        # ---- combine (reverse path, full precision) --------------------
+        if not tile_fused:
+            run_rounds(combine_round)
+
     # (a combine block lands as B/combine_tile sub-tile DMAs on the tile-
     # fused path; a block-sized wait ticks once all of them landed)
-    for s_idx in range(n):
-        src = jax.lax.rem(me + s_idx, n)
-        wait_blocks(functools.partial(wait_block, crecv, comb), src, 0,
-                    _lookup(blocks, src) if elide_dummy else b_max)
+    with phase("combine_wait"):
+        for s_idx in range(n):
+            src = jax.lax.rem(me + s_idx, n)
+            wait_blocks(functools.partial(wait_block, crecv, comb), src, 0,
+                        _lookup(blocks, src) if elide_dummy else b_max)
 
     # ---- assemble: region e holds my tokens processed by expert e ------
-    for e in range(n):
-        if counts[e] == 0:
-            continue
-        ybuf[pl.ds(offsets[e], counts[e])] = \
-            comb[pl.ds(e * stride, counts[e])].astype(ybuf.dtype)
-    pltpu.sync_copy(ybuf, y_ref)
+    with phase("assemble"):
+        for e in range(n):
+            if counts[e] == 0:
+                continue
+            ybuf[pl.ds(offsets[e], counts[e])] = \
+                comb[pl.ds(e * stride, counts[e])].astype(ybuf.dtype)
+    with phase("stage_out"):
+        pltpu.sync_copy(ybuf, y_ref)
 
 
 def moe_dispatch_combine_sharded(x, w1, w2, *, axis, sched: DispatchSchedule,

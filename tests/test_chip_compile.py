@@ -16,10 +16,16 @@ program larger than the chip's HBM. These tests compile, for the
     width, from ``jax.eval_shape`` shapes,
 
 and check that each kernel is a ``tpu_custom_call`` and that each program
-fits 16 GiB. The topology is described inside a fixture (never at import):
-only one process at a time may load the TPU compiler's library.
+fits 16 GiB. They also lower the moe_dispatch kernel with its device phases
+on and off (``repro.core.trace.device_phases``) and read the phase regions
+that Mosaic's kernel body opens. The topology is described inside a fixture
+(never at import): only one process at a time may load the TPU compiler's
+library.
 """
+import base64
+import contextlib
 import dataclasses
+import re
 
 import jax
 import numpy as np
@@ -78,6 +84,10 @@ def mosaic(monkeypatch):
 def _compile_workload(topo, name, n, d):
     """Compile ``Workload.build(d)`` on an n-rank mesh of described v5e
     chips at the workload's example-input shapes (chip_smoke's)."""
+    return _lower_workload(topo, name, n, d).compile()
+
+
+def _lower_workload(topo, name, n, d):
     wl = get_workload(name, n_dev=n) if name != "kv_transfer" \
         else get_workload(name)
     assert wl.n_dev == n
@@ -89,7 +99,7 @@ def _compile_workload(topo, name, n, d):
                             jax.random.PRNGKey(0))
     args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(
         mesh, P("x") if s.ndim >= 3 else P())) for s in shapes]
-    return jax.jit(wl.build(d, mesh)).lower(*args).compile()
+    return jax.jit(wl.build(d, mesh)).lower(*args)
 
 
 def _device_bytes(compiled):
@@ -104,6 +114,120 @@ def test_moe_dispatch_compiles_for_v5e(topo, mosaic, point, n):
     compiled = _compile_workload(topo, "moe_dispatch", n, MOE_POINTS[point])
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) <= HBM_BYTES
+
+
+def _regions(text):
+    """The phase regions of each Mosaic kernel body in a lowered program,
+    in program order, as ``(depth, name)`` per ``tpu.trace_start``."""
+    from jax._src.interpreters import mlir
+    from jaxlib.mlir import ir
+    out = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)', text):
+        with mlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(body))
+            seq, stack = [], []
+
+            def walk(op):
+                name = op.operation.name
+                if name.endswith("tpu.trace_start"):
+                    msg = ir.StringAttr(op.operation.attributes["message"])
+                    seq.append((len(stack), msg.value))
+                    stack.append(msg.value)
+                elif name.endswith("tpu.trace_stop"):
+                    stack.pop()
+                for region in op.operation.regions:
+                    for block in region.blocks:
+                        for o in block.operations:
+                            walk(o)
+
+            for op in module.body.operations:
+                walk(op)
+            assert not stack, stack
+            out.append(seq)
+    return out
+
+
+@pytest.mark.parametrize("point", sorted(MOE_POINTS))
+def test_moe_dispatch_phase_regions(topo, mosaic, point):
+    """Phases on: the kernel opens its regions in the order it runs them,
+    with one ``arrival_wait`` per dispatch microblock slot and one ``ffn``
+    per GEMM tile (per source off the tile-fused path). Phases off: no
+    region, and the same lowered text as a step lowered outside the
+    switch."""
+    from repro.core.trace import device_phases
+    from repro.kernels.moe_dispatch import PHASES, make_schedule
+    d = MOE_POINTS[point]
+    text = {}
+    for mode in ("untouched", "on", "off"):
+        with (contextlib.nullcontext() if mode == "untouched"
+              else device_phases(mode == "on")):
+            text[mode] = _lower_workload(topo, "moe_dispatch", 4, d).as_text()
+    assert text["off"] == text["untouched"]
+    assert _regions(text["off"]) == [[]]
+    (seq,) = _regions(text["on"])
+    assert seq[0] == (0, "moe_dispatch")
+    assert [m for depth, m in seq if depth == 1] == [
+        "stage_in", "dispatch", "ffn_combine", "combine_wait", "assemble",
+        "stage_out"]
+    wl = get_workload("moe_dispatch", n_dev=4)
+    d = dataclasses.replace(
+        d, tunables=tuple(sorted(wl.default_tunables().items())))
+    k = wl.kernel_knobs(d)
+    x = jax.eval_shape(lambda key: wl.example_inputs(key, None),
+                       jax.random.PRNGKey(0))[0]
+    sched = make_schedule(wl._counts(x.shape[1]), k["block_tokens"],
+                          k["tight"])
+    n, b = sched.n, sched.b_max
+    if k["tile_fused"]:
+        tiles = k["block_tokens"] // k["combine_tile"]
+        inner = (["arrival_wait"] + ["ffn"] * tiles) * (n * b)
+    elif k["barrier"] or not k["pipelined"]:
+        inner = ["arrival_wait"] * (n * b) + ["ffn"] * n
+    else:
+        inner = (["arrival_wait"] * b + ["ffn"]) * n
+    assert [m for depth, m in seq if depth == 2] == inner
+    assert {m for _, m in seq} == set(PHASES)
+
+
+def test_two_stream_phase_regions_keep_probe_marks(topo, mosaic):
+    """The serving layout's shared FFN is a ``shared_ffn`` region inside
+    ``dispatch``, and its probe marks are the serving suite's, phases on
+    or off."""
+    from repro.core import EXPERT_SYSTEMS as systems
+    from repro.core.trace import ScheduleProbe, device_phases
+    from repro.kernels.moe_dispatch import moe_dispatch_combine
+    wl = get_workload("serving_step", n_dev=4, tokens_per_rank=96, d=128,
+                      f=192, f_shared=192)
+    k = wl.kernel_knobs(systems["FLUX"])
+    mesh = make_mesh((4,), ("x",), devices=topo.devices[:4])
+    x, w1, w2, s1, s2 = [jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                              sharding=NamedSharding(
+                                                  mesh, P("x") if s.ndim >= 3
+                                                  else P()))
+                         for s in jax.eval_shape(
+                             lambda key: wl.example_inputs(key, None),
+                             jax.random.PRNGKey(0))]
+    seqs = {}
+    for on in (False, True):
+        probe = ScheduleProbe()
+
+        def step(x, w1, w2, s1, s2):
+            return moe_dispatch_combine(
+                x, w1, w2, mesh, axis="x", counts=wl._counts(x.shape[1]),
+                block_tokens=k["block_tokens"], tight=k["tight"],
+                pipelined=k["pipelined"], barrier=k["barrier"],
+                tile_fused=k["tile_fused"], combine_tile=k["combine_tile"],
+                contexts=k["contexts"], shared=(x, s1, s2), probe=probe)
+
+        with device_phases(on):
+            (seqs[on],) = _regions(
+                jax.jit(step).lower(x, w1, w2, s1, s2).as_text())
+        assert probe.marks == ["dispatch_issued", "shared_ffn",
+                               "dispatch_drained"], probe.marks
+    assert seqs[False] == []
+    i = seqs[True].index((1, "dispatch"))
+    assert seqs[True][i + 1] == (2, "shared_ffn")
 
 
 @pytest.mark.parametrize("n", [1, 4])

@@ -13,7 +13,10 @@ Tier-1 coverage that needs no simulated devices:
     serve-engine metric names;
   * a hypothesis property (skips when hypothesis is absent, matching
     test_schedules.py): replayed send-window depths never exceed the
-    ``contexts`` cap for any schedule shape.
+    ``contexts`` cap for any schedule shape;
+  * the device-phase switch: ``phase`` opens a named region only under
+    ``device_phases``, stamps an attached probe either way, and a jitted
+    step is traced anew when the switch flips.
 
 The executable 4-rank probe counterpart (observed DMA order vs the
 trace-time schedule) lives in tests/scripts/telemetry_suite.py.
@@ -29,7 +32,8 @@ from repro.core.faults import (DROPPED_PEER, STRAGGLER, FaultPlan, FaultSpec,
 from repro.core.schedule import (make_broadcast_schedule, make_ring_schedule,
                                  make_schedule)
 from repro.core.telemetry import EvalRecord, MetricsRegistry, SearchTelemetry
-from repro.core.trace import (TraceWriter, schedule_timeline, validate_trace)
+from repro.core.trace import (ScheduleProbe, TraceWriter, device_phases,
+                              phase, schedule_timeline, validate_trace)
 from repro.launch.mesh import make_mesh
 from repro.workloads import get_workload
 
@@ -282,3 +286,38 @@ else:
     @pytest.mark.skip(reason="hypothesis not installed")
     def test_send_window_depth_never_exceeds_contexts():
         pass
+
+
+def test_device_phases_switch_is_in_the_jit_key():
+    """A step traced with phases off is never served from the jit entry
+    traced with them on, nor the reverse; off, ``phase`` adds nothing to
+    the program, and an attached probe is stamped either way."""
+    import jax
+    import jax.numpy as jnp
+
+    probe, traces = ScheduleProbe(), []
+
+    def step(x):
+        traces.append(1)
+        with phase("stage", probe):
+            return x + 1
+
+    f, x = jax.jit(step), jnp.ones(4)
+    texts = {}
+    for on in (False, True, False, True):
+        with device_phases(on):
+            f(x)
+            texts[on] = f.lower(x).as_text(debug_info=True)
+    assert len(traces) == 2
+    assert probe.marks == ["stage", "stage"]
+    assert "/stage/add" in texts[True]
+    assert "/stage/" not in texts[False]
+
+    def plain():
+        def step(x):
+            return x + 1
+        return step
+
+    with device_phases(False):
+        off = jax.jit(step).lower(x).as_text()
+    assert off == jax.jit(plain()).lower(x).as_text()
