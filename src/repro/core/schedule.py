@@ -25,7 +25,9 @@ checked statically by ``core/verify.py``):
    a permutation.
 2. ``send_window_depths(contexts)`` mirrors the kernels' bounded-issue
    algorithm: at most ``contexts`` rounds' send semaphores stay unawaited;
-   the oldest is ``wait_send``-ed before the next round issues.
+   the oldest is ``wait_send``-ed before the next round issues. A window
+   may be keyed (moe_dispatch's tile-fused path keys it by the shift
+   offset, one peer per key): the cap then holds per key.
 3. ``issued_rounds()`` / ``completion_ticks()`` are the DMA-issue and
    receive-readiness counts the cost model charges ``TILE_SYNC`` per event.
 4. Receive semaphores with one slot per peer are indexed by the *sender's*
@@ -48,7 +50,8 @@ __all__ = [
     "CollectiveSchedule", "DispatchSchedule", "BroadcastSchedule",
     "RingSchedule", "SendWindow", "make_schedule",
     "make_broadcast_schedule", "make_ring_schedule", "block_counts",
-    "send_window_depths", "sanitize_tile", "sanitize_combine_tile",
+    "send_window_depths", "offset_key", "sanitize_tile",
+    "sanitize_combine_tile",
     "sanitize_tile_m", "sanitize_kv_chunk", "check_live",
     "respill_counts",
 ]
@@ -57,20 +60,39 @@ __all__ = [
 # ------------------------------------------------------------ shared pieces
 
 
-def send_window_depths(rounds, contexts):
+def _window_replay(rounds, contexts, key=None):
+    """The bounded-issue algorithm replayed over ``rounds``: for each round,
+    the depth of its key's window once it has issued, and the rounds still
+    in flight as it starts. ``key`` (a function of a round, ``None`` for one
+    window over all rounds) names a round's window."""
+    cap = max(1, int(contexts))
+    inflight = []             # (key, round) of unawaited sends, oldest first
+    for rnd in rounds:
+        k = None if key is None else key(rnd)
+        mine = [i for i, e in enumerate(inflight) if e[0] == k]
+        if len(mine) >= cap:
+            inflight.pop(mine[0])
+        before = [r for _k, r in inflight]
+        inflight.append((k, rnd))
+        yield min(len(mine) + 1, cap), before
+
+
+def send_window_depths(rounds, contexts, key=None):
     """In-flight send depth after each issued round under a ``contexts``-
     deep window — the kernels' issue algorithm (wait_send the oldest
     in-flight round before issuing past the cap) mirrored at trace time.
-    Shared by every :class:`CollectiveSchedule` and property-tested in
-    tests/test_schedules.py."""
-    cap = max(1, int(contexts))
-    depth, out = 0, []
-    for _ in rounds:
-        if depth >= cap:
-            depth -= 1
-        depth += 1
-        out.append(depth)
-    return out
+    With ``key`` (a function of a round) each key has a window of its own
+    and the depth is that of the round's key: entries with different keys
+    never retire each other. Shared by every :class:`CollectiveSchedule`
+    and property-tested in tests/test_schedules.py."""
+    return [d for d, _ in _window_replay(rounds, contexts, key)]
+
+
+def offset_key(rnd):
+    """Window key of a moe_dispatch round ``(off, j[, t])``: its shift
+    offset, which names exactly one peer on every rank (a trace-time int,
+    although the peer ``(me -/+ off) % n`` itself is traced)."""
+    return rnd[0]
 
 
 class SendWindow:
@@ -78,10 +100,12 @@ class SendWindow:
     :func:`send_window_depths` (one code path for all four kernels, so the
     property-tested trace-time mirror and the issued DMAs cannot drift).
 
-    At most ``contexts`` *rounds'* send semaphores stay unawaited; the
-    oldest round is waited before the next one issues. A round may span
-    several DMA descriptors (a K/V chunk pair, a data+scale pair): they
-    count as ONE window entry — :meth:`push` opens a round and
+    At most ``contexts`` *rounds'* send semaphores stay unawaited per
+    window key; the oldest round of the key is waited before the next one
+    of that key issues. Unkeyed pushes (``key=None``, what
+    gemm_allgather, ring_attention and kv_shuttle do) share one window. A
+    round may span several DMA descriptors (a K/V chunk pair, a data+scale
+    pair): they count as ONE window entry — :meth:`push` opens a round and
     :meth:`amend` adds a descriptor issued later in the same round.
 
     ``start``/``wait`` hooks customize how an entry's descriptors are
@@ -92,29 +116,32 @@ class SendWindow:
 
     def __init__(self, contexts, *, start=None, wait=None):
         self.cap = max(1, int(contexts))
-        self._rounds = []
+        self._rounds = []              # (key, entry), oldest first
         self._start = start or (lambda cps: [cp.start() for cp in cps])
         self._wait = wait or (lambda cps: [cp.wait_send() for cp in cps])
 
-    def push(self, entry):
-        """Open a new round: retire the oldest past the cap, then start.
-        ``entry`` is a list of descriptors (mutable, so :meth:`amend` can
-        extend it) — or any opaque value when custom hooks are given."""
-        if len(self._rounds) >= self.cap:
-            self._wait(self._rounds.pop(0))
+    def push(self, entry, key=None):
+        """Open a new round: retire the oldest of ``key``'s rounds past the
+        cap, then start. ``entry`` is a list of descriptors (mutable, so
+        :meth:`amend` can extend it) — or any opaque value when custom
+        hooks are given."""
+        mine = [i for i, (k, _e) in enumerate(self._rounds) if k == key]
+        if len(mine) >= self.cap:
+            self._wait(self._rounds.pop(mine[0])[1])
         self._start(entry)
-        self._rounds.append(entry)
+        self._rounds.append((key, entry))
 
     def amend(self, cp):
         """Start a descriptor belonging to the most recent round (e.g. the
         V half of a K/V pair issued after the V tile's GEMM)."""
         cp.start()
-        self._rounds[-1].append(cp)
+        self._rounds[-1][1].append(cp)
 
     def drain(self):
-        """Retire every in-flight round (step/kernel boundary)."""
+        """Retire every in-flight round, oldest first (step/kernel
+        boundary)."""
         while self._rounds:
-            self._wait(self._rounds.pop(0))
+            self._wait(self._rounds.pop(0)[1])
 
 
 def check_live(live_ranks, n):
@@ -252,6 +279,12 @@ class DispatchSchedule(CollectiveSchedule):
     edge has fewer than ``j + 1`` real blocks ship a dummy block into the
     receiver's trash row to keep the permutation total; real hardware
     elides them (``elide_dummy``).
+
+    ``rounds`` is offset-major, the order the per-source (BARRIER/SIGNAL)
+    paths consume; the tile-fused path issues the same rounds
+    microblock-major (``block_major_rounds``), through one send window per
+    offset (:meth:`send_phases`, :func:`offset_key`), so that consecutive
+    sends go to different peers.
     """
     n: int
     block_tokens: int
@@ -267,6 +300,50 @@ class DispatchSchedule(CollectiveSchedule):
     def rounds(self):
         return [(off, j) for off in range(self.n)
                 for j in range(self.b_max)]
+
+    @property
+    def block_major_rounds(self):
+        """The same rounds microblock-major, ``(off, j)`` for each ``j`` in
+        turn: every round is still a shift permutation, identical on every
+        rank."""
+        return [(off, j) for j in range(self.b_max)
+                for off in range(self.n)]
+
+    def send_phases(self, tile_fused=False, combine_tile=None):
+        """The kernel's two send windows, each as its rounds in issue order,
+        and the window key: dispatch ``(off, j)``, then combine — one round
+        per block off the tile-fused path, ``(off, j, t)`` per combine tile
+        on it. Each window drains before the next opens. The tile-fused path
+        issues block-major and windows each offset apart
+        (:func:`offset_key`); the per-source paths issue ``rounds`` through
+        one window (key ``None``)."""
+        if not tile_fused:
+            return (self.rounds, self.rounds), None
+        nt = self.block_tokens // sanitize_combine_tile(combine_tile,
+                                                        self.block_tokens)
+        rounds = self.block_major_rounds
+        return (rounds, [(off, j, t) for off, j in rounds
+                         for t in range(nt)]), offset_key
+
+    def overlap_share(self, contexts, rank=0, *, tile_fused=True,
+                      combine_tile=None, elide_dummy=True):
+        """Share of the send-window entries rank ``rank`` issues (dispatch
+        rounds and combine rounds or tiles) that start while a send to
+        another peer is still in flight, from the keyed window mirror. With
+        ``elide_dummy`` the dummy rounds, never started, are left out."""
+        n, blocks = self.n, self.blocks
+        phases, key = self.send_phases(tile_fused, combine_tile)
+        real = (lambda r: r[1] < blocks[(rank - r[0]) % n],     # dispatch
+                lambda r: r[1] < blocks[rank])                  # combine
+        issued = overlapped = 0
+        for rounds, is_real in zip(phases, real):
+            if elide_dummy:
+                rounds = [r for r in rounds if is_real(r)]
+            for rnd, (_d, before) in zip(
+                    rounds, _window_replay(rounds, contexts, key)):
+                issued += 1
+                overlapped += any(r[0] != rnd[0] for r in before)
+        return overlapped / issued if issued else 0.0
 
     def wire_tokens(self, rank=0):
         """Exact off-rank tokens rank ``rank`` dispatches (the l3 credit):

@@ -25,11 +25,11 @@ from the same :class:`~repro.core.cost_model.CostBreakdown`, so the trace
 audits exactly the scalar the cascade scores.
 
 :class:`ScheduleProbe` is the interpret-mode observed-order probe: a
-kernel body (``kernels/gemm_allgather.py``) records its actual DMA
-issue/wait sequence at trace time, and :meth:`ScheduleProbe.check`
-verifies it against the trace-time lockstep schedule — round order,
-window cap, and arrival count must match the ``CollectiveSchedule``
-contract the cost model charged.
+kernel body (``kernels/gemm_allgather.py``, ``kernels/moe_dispatch.py``)
+records its actual DMA issue/wait sequence at trace time, and
+:meth:`ScheduleProbe.check` verifies it against the trace-time lockstep
+schedule — round order, window cap, and arrival count must match the
+``CollectiveSchedule`` contract the cost model charged.
 
 :func:`phase` marks a kernel's phases on the device's own clock: a Pallas
 TPU kernel body opens one region per phase (``jax.named_scope``, which
@@ -54,6 +54,7 @@ from jax._src import config as jax_config
 
 from repro.core.cost_model import CostSegment
 from repro.core.faults import REMESH_OVERHEAD
+from repro.core.schedule import DispatchSchedule, send_window_depths
 
 __all__ = [
     "TraceWriter", "Timeline", "ScheduleProbe", "schedule_timeline",
@@ -263,6 +264,15 @@ def schedule_timeline(workload, directive, hw, *, live_ranks=None,
         # ------------- schedule detail tracks (kernelized directives only)
         rounds = list(sched.rounds)
         depths = sched.send_window_depths(contexts)
+        if isinstance(sched, DispatchSchedule) and bd.knobs.get("tile_fused"):
+            # moe_dispatch's tile-fused dispatch window: block-major, one
+            # window per offset (DispatchSchedule.send_phases)
+            (rounds, _), key = sched.send_phases(True)
+            depths = send_window_depths(rounds, contexts, key)
+            w.instant("send overlap", 0.0, pid=rank, tid=TID_DMA, cat="dma",
+                      args={"overlap_share": sched.overlap_share(
+                          contexts, rank, combine_tile=bd.knobs.get(
+                              "combine_tile"))})
         anchor = _anchor_segment(bd)
         a0 = 0.0
         for seg in bd.segments:
@@ -319,11 +329,15 @@ class ScheduleProbe:
     :meth:`wait_send` / :meth:`wait_recv` next to the corresponding DMA
     operations; :meth:`check` asserts the ``CollectiveSchedule`` contract:
 
-    * the issued ``(edge, tile)`` order equals ``schedule.rounds``,
-    * the replayed in-flight send depth never exceeds ``contexts`` and
-      matches ``send_window_depths`` after every issue,
-    * every in-flight send is retired (drained) by kernel end,
+    * the issued round order equals the schedule's (``schedule.rounds``,
+      or each send window's rounds in turn),
+    * the replayed in-flight send depth of each window key never exceeds
+      ``contexts`` and matches ``send_window_depths`` after every issue,
+    * every in-flight send is retired (drained) by each window's end,
     * the receive-wait count equals ``completion_ticks``.
+
+    A round's first element (its edge) names its peer: the shift offset in
+    the dispatch and broadcast schedules.
     """
 
     def __init__(self):
@@ -332,11 +346,14 @@ class ScheduleProbe:
     def reset(self):
         self.events = []
 
-    def issue(self, edge, tile):
-        self.events.append(("issue", int(edge), int(tile)))
+    def issue(self, *rnd, key=None):
+        """Round ``rnd`` (``(edge, tile)``, or ``(off, j, t)`` for a combine
+        tile) starts in send window ``key`` (``None``: the one window)."""
+        self.events.append(("issue", tuple(int(x) for x in rnd), key))
 
-    def wait_send(self):
-        self.events.append(("wait_send",))
+    def wait_send(self, key=None):
+        """The oldest in-flight round of window ``key`` retires."""
+        self.events.append(("wait_send", key))
 
     def wait_recv(self, slot=None):
         self.events.append(("wait_recv",
@@ -355,36 +372,65 @@ class ScheduleProbe:
 
     @property
     def issued(self):
-        return [(e[1], e[2]) for e in self.events if e[0] == "issue"]
+        return [e[1] for e in self.events if e[0] == "issue"]
 
     @property
     def recv_waits(self):
         return [e for e in self.events if e[0] == "wait_recv"]
 
-    def check(self, schedule, contexts, counter=True):
+    def check(self, schedule, contexts, counter=True, *, phases=None,
+              key=None):
         """Assert the observed order satisfies the schedule contract;
         returns a summary dict on success, raises ``AssertionError`` with
-        the first divergence otherwise."""
+        the first divergence otherwise.
+
+        ``phases`` lists each send window's rounds in issue order, each
+        window drained before the next opens (default: ``schedule.rounds``
+        through one window); ``key`` is the windows' key function (see
+        ``core/schedule.py::send_window_depths``). The summary's
+        ``peers_in_flight`` is the most peers with an unawaited send at
+        once, and ``overlap_share`` the share of issues that start while a
+        send to another peer is in flight."""
         cap = max(1, int(contexts))
-        rounds = list(schedule.rounds)
+        if phases is None:
+            phases = [list(schedule.rounds)]
+            expect = list(schedule.send_window_depths(cap))
+        else:
+            phases = [list(p) for p in phases]
+            expect = [d for p in phases
+                      for d in send_window_depths(p, cap, key)]
+        rounds = [tuple(r) for p in phases for r in p]
         assert self.issued == rounds, (
-            f"observed issue order diverges from schedule.rounds:\n"
+            f"observed issue order diverges from the schedule:\n"
             f"  observed {self.issued[:8]}...\n  expected {rounds[:8]}...")
-        depth, depths = 0, []
+        inflight, depths, peers, overlapped = [], [], 0, 0
+        bounds = {sum(len(p) for p in phases[:i + 1])
+                  for i in range(len(phases))}
         for ev in self.events:
             if ev[0] == "issue":
-                depth += 1
+                if len(depths) in bounds:
+                    assert not inflight, (
+                        f"{len(inflight)} sends left in flight where a "
+                        f"window ends (not drained)")
+                rnd, k = ev[1], ev[2]
+                overlapped += any(p != rnd[0] for _k, p in inflight)
+                inflight.append((k, rnd[0]))
+                depth = sum(1 for e in inflight if e[0] == k)
                 assert depth <= cap, (
-                    f"send window exceeded: depth {depth} > contexts {cap}")
+                    f"send window {k} exceeded: depth {depth} > "
+                    f"contexts {cap}")
                 depths.append(depth)
+                peers = max(peers, len({p for _k, p in inflight}))
             elif ev[0] == "wait_send":
-                depth -= 1
-                assert depth >= 0, "wait_send with no in-flight send"
-        assert depth == 0, f"{depth} sends left in flight (window not drained)"
-        expect = schedule.send_window_depths(cap)
-        assert depths == list(expect), (
+                mine = [e for e in inflight if e[0] == ev[1]]
+                assert mine, f"wait_send on window {ev[1]} with no " \
+                    "in-flight send"
+                inflight.remove(mine[0])
+        assert not inflight, \
+            f"{len(inflight)} sends left in flight (window not drained)"
+        assert depths == expect, (
             f"window depth profile diverges from send_window_depths:\n"
-            f"  observed {depths[:12]}...\n  expected {list(expect)[:12]}...")
+            f"  observed {depths[:12]}...\n  expected {expect[:12]}...")
         ticks = schedule.completion_ticks(counter) \
             if hasattr(schedule, "completion_ticks") else None
         n_recv = len(self.recv_waits)
@@ -392,7 +438,8 @@ class ScheduleProbe:
             assert n_recv == ticks, (
                 f"receive waits {n_recv} != completion_ticks {ticks}")
         return {"rounds": len(rounds), "max_depth": max(depths, default=0),
-                "recv_waits": n_recv}
+                "recv_waits": n_recv, "peers_in_flight": peers,
+                "overlap_share": overlapped / len(depths) if depths else 0.0}
 
 
 # ------------------------------------------------------- device phases

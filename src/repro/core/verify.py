@@ -107,7 +107,8 @@ class Op:
                         ``opens`` opens a new send-window entry; ``False``
                         amends the current one (K/V pair, data+scale pair).
       * ``wait``      — consume ``rows`` arrival ticks from ``(rank, sem)``.
-      * ``wait_send`` — retire the oldest in-flight send-window entry.
+      * ``wait_send`` — retire the oldest in-flight send-window entry of
+                        window ``key``.
       * ``write``     — local compute producing ``writes`` regions.
       * ``read``      — local compute consuming ``reads`` regions.
       * ``signal``    — bump ``(dst, sem)`` by ``rows`` with no payload
@@ -124,6 +125,7 @@ class Op:
     signals: bool = True         # dma only: bump the receive semaphore
     dummy: bool = False          # trash-row round (excluded from conservation)
     opens: bool = True           # dma only: opens a new window entry
+    key: object = None           # dma/wait_send: the send window (None: one)
     counted: bool = True         # dma only: counts toward edge conservation
     label: str = ""
 
@@ -197,33 +199,33 @@ class VerifyReport:
 
 
 class _Builder:
-    """Per-rank op emission with a ``SendWindow`` depth mirror: ``push_dma``
-    retires the oldest entry before issuing past the ``contexts`` cap —
+    """Per-rank op emission with a ``SendWindow`` mirror: ``push_dma``
+    retires the oldest entry of its window ``key`` before issuing past the
+    ``contexts`` cap, and ``drain`` retires every entry oldest first —
     byte-for-byte the kernels' bounded-issue algorithm."""
 
     def __init__(self, n, contexts):
         self.n = n
         self.contexts = max(1, int(contexts))
         self.ops = [[] for _ in range(n)]
-        self._depth = [0] * n
+        self._inflight = [[] for _ in range(n)]   # window keys, oldest first
 
     def emit(self, r, op):
         self.ops[r].append(op)
 
-    def push_dma(self, r, **kw):
-        if self._depth[r] >= self.contexts:
-            self.emit(r, Op("wait_send"))
-            self._depth[r] -= 1
-        self.emit(r, Op("dma", opens=True, **kw))
-        self._depth[r] += 1
+    def push_dma(self, r, key=None, **kw):
+        if self._inflight[r].count(key) >= self.contexts:
+            self.emit(r, Op("wait_send", key=key))
+            self._inflight[r].remove(key)
+        self.emit(r, Op("dma", opens=True, key=key, **kw))
+        self._inflight[r].append(key)
 
     def amend_dma(self, r, **kw):
         self.emit(r, Op("dma", opens=False, **kw))
 
     def drain(self, r):
-        while self._depth[r]:
-            self.emit(r, Op("wait_send"))
-            self._depth[r] -= 1
+        while self._inflight[r]:
+            self.emit(r, Op("wait_send", key=self._inflight[r].pop(0)))
 
     def wait(self, r, sem, rows):
         if rows > 0:
@@ -240,12 +242,17 @@ def lower_dispatch(sched, contexts, *, wire_i8=False, tile_fused=False,
     the full lockstep dispatch round list (dummies to the trash row), the
     three wait realizations (barrier rendezvous / pipelined real-block
     waits + dummy residue / tile-fused per-microblock combine), and the
-    reverse combine permutation."""
+    reverse combine permutation — in the order and through the send
+    windows of ``DispatchSchedule.send_phases``: block-major with one
+    window per offset on the tile-fused path, offset-major through one
+    window on the per-source paths."""
     n, B, b_max = sched.n, sched.block_tokens, sched.b_max
     blocks = sched.blocks
     ct = sanitize_combine_tile(combine_tile, B)
     nt = B // ct
     bld = _Builder(n, contexts)
+    (order, _), key = sched.send_phases(tile_fused, combine_tile)
+    key = key or (lambda rnd: None)
     P1, P2 = "dispatch", "combine"
 
     # stage every real microblock into the send queue (+ its scale row)
@@ -257,12 +264,13 @@ def lower_dispatch(sched, contexts, *, wire_i8=False, tile_fused=False,
                     bld.emit(r, Op("write", writes=(("sends", e, j),)))
 
     # lockstep dispatch rounds: rank r -> expert (r - off) % n
-    for ri, (off, j) in enumerate(sched.rounds):
+    for ri, (off, j) in enumerate(order):
         for r in range(n):
             e = (r - off) % n
             real = j < blocks[e]
             bld.push_dma(
-                r, phase=P1, rnd=ri, dst=e, sem=("disp", r), rows=B,
+                r, key((off, j)), phase=P1, rnd=ri, dst=e, sem=("disp", r),
+                rows=B,
                 writes=(("recv", r, j),) if real else ((_TRASH,),),
                 reads=(("send", e, j),) if real else (),
                 dummy=not real)
@@ -293,25 +301,24 @@ def lower_dispatch(sched, contexts, *, wire_i8=False, tile_fused=False,
 
     if tile_fused:
         # per-microblock arrival waits interleaved with the sub-tile
-        # combine pushes (the FLUX point) — one shared combine window
+        # combine pushes (the FLUX point), block-major, each offset's
+        # combine tiles through a window of their own
         for r in range(n):
             my = blocks[r]
-            for off in range(n):
+            for ri, (off, j) in enumerate(order):
                 src = (r + off) % n       # dispatch source == combine dst
-                for j in range(b_max):
-                    real = j < my
-                    _wait_edge(r, src, 1)
-                    if real:
-                        _ffn(r, src, j, j + 1)
-                    ri = off * b_max + j
-                    for t in range(nt):
-                        bld.push_dma(
-                            r, phase=P2, rnd=ri, dst=src,
-                            sem=("comb", r), rows=ct,
-                            writes=(("comb", r, j, t),) if real
-                            else ((_TRASH,),),
-                            reads=(("ffn", src, j, t),) if real else (),
-                            dummy=not real)
+                real = j < my
+                _wait_edge(r, src, 1)
+                if real:
+                    _ffn(r, src, j, j + 1)
+                for t in range(nt):
+                    bld.push_dma(
+                        r, key((off, j, t)), phase=P2, rnd=ri, dst=src,
+                        sem=("comb", r), rows=ct,
+                        writes=(("comb", r, j, t),) if real
+                        else ((_TRASH,),),
+                        reads=(("ffn", src, j, t),) if real else (),
+                        dummy=not real)
             bld.drain(r)
     else:
         if barrier or not pipelined:
@@ -594,7 +601,8 @@ class _Executor:
         self.p = prog
         self.errors = []
         self.clock = [[0] * prog.n for _ in range(prog.n)]
-        self.window = [[] for _ in range(prog.n)]     # entries: [dma records]
+        # per rank, oldest first: (window key, [dma records])
+        self.window = [[] for _ in range(prog.n)]
         self.pending = {}        # (rank, sem) -> list of _Delivery (FIFO)
         self.unsignaled = {}     # (rank, sem) -> rows delivered sans signal
         self.regions = {}        # (rank, key) -> _Region
@@ -663,13 +671,15 @@ class _Executor:
                 reg.open_reads.append(entry)
                 rec_reads.append((reg, entry))
             if op.opens:
-                if len(self.window[r]) >= self.p.contexts:
+                depth = sum(1 for k, _e in self.window[r] if k == op.key)
+                if depth >= self.p.contexts:
                     self.err("window-overflow", r, idx,
-                             f"send depth {len(self.window[r]) + 1} exceeds "
-                             f"contexts={self.p.contexts} at round {op.rnd}")
-                self.window[r].append([rec_reads])
+                             f"send depth {depth + 1} of window {op.key} "
+                             f"exceeds contexts={self.p.contexts} at round "
+                             f"{op.rnd}")
+                self.window[r].append((op.key, [rec_reads]))
             elif self.window[r]:
-                self.window[r][-1].append(rec_reads)
+                self.window[r][-1][1].append(rec_reads)
             writes = []
             label = f"DMA round {op.rnd} from rank {r}"
             for key in op.writes:
@@ -712,11 +722,14 @@ class _Executor:
         elif k == "wait_send":
             self._event(r)
             ec = tuple(self.clock[r])
-            if not self.window[r]:
+            mine = [i for i, (k, _e) in enumerate(self.window[r])
+                     if k == op.key]
+            if not mine:
                 self.err("window-overflow", r, idx,
-                         "send-window retire with nothing in flight")
+                         f"send-window {op.key} retire with nothing in "
+                         f"flight")
                 return
-            entry = self.window[r].pop(0)
+            entry = self.window[r].pop(mine[0])[1]
             for rec_reads in entry:
                 for reg, oread in rec_reads:
                     if oread in reg.open_reads:

@@ -34,15 +34,21 @@ later peers are still in flight (``TILE_PIPELINED``); ``BARRIER`` drains
 every edge before any compute (DeepEP-NVL's conservative point); ``COUNTER``
 (the FLUX point, ``tile_fused``) consumes dispatch arrivals one microblock
 at a time and treats each landed/produced tile as a counter tick.
-``contexts`` bounds the in-flight send window (double buffering).
+``contexts`` bounds the in-flight send window (double buffering): on the
+per-source paths one window over all rounds, on the tile-fused path one
+window per destination (keyed by the shift offset, matching the one send
+semaphore per peer), so sends to different peers fly at once.
 
 **Tile-fused combine (FLUX / CoCoNet point):** with ``tile_fused`` the
 expert FFN runs as a tiled GEMM loop over ``combine_tile``-row tiles and
 the combine remote-DMA for each output tile is issued the moment that tile
 is ready — instead of finishing the whole per-source FFN before any
-combine round. The trace-time round order ``(off, j, t)`` is identical on
-every rank; in the padded schedule every combine DMA is issued (dummy
-tiles go to the trash row).
+combine round. This path issues its rounds microblock-major: dispatch
+``(j, off)`` and the FFN/combine loop ``(j, off, t)``
+(:attr:`DispatchSchedule.block_major_rounds`), so consecutive window
+entries go to different peers. The order is identical on every rank; in
+the padded schedule every combine DMA is issued (dummy tiles go to the
+trash row).
 
 **Dummy elision:** with ``elide_dummy`` (default whenever the kernel is
 Mosaic-compiled) dummy-slot DMAs are predicated away with ``pl.when`` and
@@ -190,9 +196,7 @@ def _moe_body(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
                 cp.start()
         pl.when(real)(go) if elide_dummy else go()
 
-    def _wait_sent(entry):
-        real, cps = entry
-
+    def _wait_sent(real, cps):
         def go():
             for cp in cps:
                 cp.wait_send()
@@ -223,22 +227,42 @@ def _moe_body(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
         cp = _dma(ffn_out, comb, csend, crecv, src_off, dst_off, q, j, rows)
         return real, [cp]
 
+    # The send windows' rounds and key (schedule.send_phases): on the
+    # tile-fused path block-major, one window per offset; on the per-source
+    # paths offset-major, one window.
+    (dispatch_order, _), window_key = sched.send_phases(tile_fused)
+
     def make_window():
         """The shared contexts-deep send window (schedule.SendWindow) with
         the elide_dummy hooks: a round's start and wait_send both sit under
-        the same pl.when(real) so the send semaphore stays balanced."""
-        return SendWindow(contexts, start=lambda e: _start(*e),
-                          wait=_wait_sent)
+        the same pl.when(real) so the send semaphore stays balanced. An
+        attached probe records each round's issue and retirement."""
+        def start(entry):
+            rnd, key, real, cps = entry
+            if probe is not None:
+                probe.issue(*rnd, key=key)
+            _start(real, cps)
 
-    def run_rounds(round_fn, between=None, tag=None):
-        """Issue all rounds with a bounded in-flight send window.
+        def wait(entry):
+            _rnd, key, real, cps = entry
+            if probe is not None:
+                probe.wait_send(key)
+            _wait_sent(real, cps)
+
+        return SendWindow(contexts, start=start, wait=wait)
+
+    def push(window, rnd, real_cps):
+        key = None if window_key is None else window_key(rnd)
+        window.push((rnd, key, *real_cps), key=key)
+
+    def run_rounds(round_fn, order, between=None, tag=None):
+        """Issue ``order``'s rounds with a bounded in-flight send window.
         ``between`` runs after the last round is pushed but *before* the
         window drains — compute issued against in-flight sends (the
         two-stream overlap slot). ``tag`` stamps probe marks around it."""
         window = make_window()
-        for off in range(n):
-            for j in range(b_max):
-                window.push(round_fn(off, j))
+        for off, j in order:
+            push(window, (off, j), round_fn(off, j))
         if probe is not None and tag:
             probe.mark(f"{tag}_issued")
         if between is not None:
@@ -303,7 +327,7 @@ def _moe_body(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
     # (with `shared`, the shared-expert stream runs against the open
     # dispatch send window — before the drain, after the last issue)
     with phase("dispatch"):
-        run_rounds(dispatch_round,
+        run_rounds(dispatch_round, dispatch_order,
                    between=shared_compute if shared else None, tag="dispatch")
 
     # ---- expert FFN and combine ----------------------------------------
@@ -315,26 +339,25 @@ def _moe_body(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
             # consumed one microblock at a time (counter ticks on the edge
             # semaphore), so the first tile computes while later peers are
             # still in flight — and its combine write goes out before the
-            # next tile's GEMM.
+            # next tile's GEMM. Block-major: block j of every source in
+            # turn, so each tile returns to a different peer than the last.
             ct = combine_tile          # sanitized by the sharded entry
             window = make_window()
-            for off in range(n):
+            for off, j in dispatch_order:
                 src = jax.lax.rem(me + off, n)             # source region
-                for j in range(b_max):
-                    real = j < my_blocks
+                real = j < my_blocks
 
-                    # dummy rounds are never sent under elide_dummy, so
-                    # the arrival wait is predicated away like every other
-                    # elided op
-                    arrive = functools.partial(wait_dispatch, src, j)
-                    pl.when(real)(arrive) if elide_dummy else arrive()
-                    for t in range(B // ct):
-                        # with elide_dummy, dummy tiles skip the GEMM too —
-                        # their combine DMA is elided, so nothing reads them
-                        def tile(rel=j * B + t * ct):
-                            ffn_tile(src, rel, ct)
-                        pl.when(real)(tile) if elide_dummy else tile()
-                        window.push(combine_round(off, j, t, ct))
+                # dummy rounds are never sent under elide_dummy, so the
+                # arrival wait is predicated away like every other elided op
+                arrive = functools.partial(wait_dispatch, src, j)
+                pl.when(real)(arrive) if elide_dummy else arrive()
+                for t in range(B // ct):
+                    # with elide_dummy, dummy tiles skip the GEMM too —
+                    # their combine DMA is elided, so nothing reads them
+                    def tile(rel=j * B + t * ct):
+                        ffn_tile(src, rel, ct)
+                    pl.when(real)(tile) if elide_dummy else tile()
+                    push(window, (off, j, t), combine_round(off, j, t, ct))
             window.drain()
         elif barrier or not pipelined:
             # BARRIER / DEFERRED: global rendezvous — drain every edge
@@ -363,7 +386,7 @@ def _moe_body(*refs, axis, sched: DispatchSchedule, offsets, pipelined,
 
         # ---- combine (reverse path, full precision) --------------------
         if not tile_fused:
-            run_rounds(combine_round)
+            run_rounds(combine_round, sched.rounds)
 
     # (a combine block lands as B/combine_tile sub-tile DMAs on the tile-
     # fused path; a block-sized wait ticks once all of them landed)

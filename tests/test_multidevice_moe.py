@@ -10,6 +10,17 @@ def test_moe_dispatch_deepep_kernel():
     assert "ALL OK" in out
 
 
+def test_flux_send_windows_per_destination():
+    """The FLUX point under a scaled 3:1 routing law, with and without the
+    shared FFN: the output matches the f32 reference, the kernel issues
+    block-major rounds through one window per destination with sends to
+    several peers in flight, and the DeepEP points issue sched.rounds
+    through one window."""
+    out = run_script("moe_window_suite.py")
+    assert "ALL OK" in out
+    assert "flux shared=True" in out
+
+
 def test_moe_dispatch_8rank():
     """The executable counterpart of the fig4 --n-dev 8 analytic sweep
     (ROADMAP open item): the suite's budget-capped path at 8 simulated

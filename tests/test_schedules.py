@@ -18,10 +18,11 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.core.schedule import (make_broadcast_schedule, make_ring_schedule,
-                                 make_schedule, respill_counts,
+from repro.core.schedule import (SendWindow, make_broadcast_schedule,
+                                 make_ring_schedule, make_schedule,
+                                 offset_key, respill_counts,
                                  sanitize_combine_tile, sanitize_kv_chunk,
-                                 sanitize_tile_m)
+                                 sanitize_tile_m, send_window_depths)
 
 # ----------------------------------------------------- strategy definitions
 
@@ -126,6 +127,112 @@ def test_dispatch_wire_accounting_consistent(s):
         # the exact l3 credit never exceeds the block-rounded execution
         assert s.wire_tokens(rank) <= executed or not s.tight
     assert s.issued_rounds(elide_dummy=True) <= s.issued_rounds()
+
+
+@given(disp_scheds)
+@settings(max_examples=200, deadline=None)
+def test_dispatch_block_major_order(s):
+    """The tile-fused path's order: every (off, j) exactly once,
+    microblock-major, each round a shift permutation — rank r sends to
+    (r - off) % n, so every rank receives exactly once — and a pure
+    function of the schedule, the same on every rank."""
+    rounds = s.block_major_rounds
+    assert len(rounds) == len(set(rounds)) == s.n * s.b_max
+    assert set(rounds) == set(s.rounds)
+    assert rounds == sorted(rounds, key=lambda r: (r[1], r[0]))
+    for off, _j in rounds:
+        assert sorted((r - off) % s.n for r in range(s.n)) \
+            == list(range(s.n))
+    assert rounds == s.block_major_rounds
+    # the per-source paths keep the offset-major order
+    assert s.rounds == sorted(s.rounds)
+
+
+def _window_events(rounds, cap, key):
+    """Push ``rounds`` through a SendWindow with recording hooks and drain
+    it: the start/retire event list and each round's in-flight depth."""
+    events, depth = [], {}
+
+    def start(rnd):
+        k = None if key is None else key(rnd)
+        depth[k] = depth.get(k, 0) + 1
+        events.append(("start", rnd, depth[k]))
+
+    def wait(rnd):
+        k = None if key is None else key(rnd)
+        depth[k] -= 1
+        events.append(("wait", rnd))
+
+    window = SendWindow(cap, start=start, wait=wait)
+    for rnd in rounds:
+        window.push(rnd, key=None if key is None else key(rnd))
+    window.drain()
+    return events, depth
+
+
+@given(disp_scheds, contexts, st.booleans(), st.sampled_from((None, 8)))
+@settings(max_examples=200, deadline=None)
+def test_keyed_send_window_per_key_depth_and_drain(s, ctx, fused, ct):
+    """One window per offset: each key's depth stays within 1..contexts,
+    the executable SendWindow matches the keyed mirror after every push,
+    and the drain retires every round exactly once."""
+    for rounds in s.send_phases(fused, ct)[0]:
+        depths = send_window_depths(rounds, ctx, offset_key)
+        assert len(depths) == len(rounds)
+        assert all(1 <= d <= ctx for d in depths)
+        events, left = _window_events(rounds, ctx, offset_key)
+        assert [e[2] for e in events if e[0] == "start"] == depths
+        assert sorted(e[1] for e in events if e[0] == "wait") \
+            == sorted(rounds)
+        assert all(v == 0 for v in left.values())
+        # a key's window saturates once the key has enough rounds
+        per_key = {}
+        for rnd in rounds:
+            per_key[offset_key(rnd)] = per_key.get(offset_key(rnd), 0) + 1
+        for k, cnt in per_key.items():
+            kd = [d for r, d in zip(rounds, depths) if offset_key(r) == k]
+            assert max(kd) == min(ctx, cnt)
+
+
+def _serial_window_depths(rounds, contexts):
+    """The one-window algorithm as every kernel ran it before windows had
+    keys: retire the oldest round once ``contexts`` are in flight."""
+    cap = max(1, int(contexts))
+    depth, out = 0, []
+    for _ in rounds:
+        if depth >= cap:
+            depth -= 1
+        depth += 1
+        out.append(depth)
+    return out
+
+
+@given(st.one_of(bcast_scheds, disp_scheds, ring_scheds), contexts)
+@settings(max_examples=200, deadline=None)
+def test_unkeyed_send_window_unchanged(s, ctx):
+    """Without a key (gemm_allgather, ring_attention, kv_shuttle and the
+    per-source moe_dispatch paths) the mirror and the executable window
+    are one window over all rounds: the same depths and the same
+    retire-oldest order as before."""
+    rounds = list(s.rounds)
+    assert send_window_depths(rounds, ctx) \
+        == _serial_window_depths(rounds, ctx)
+    events, _ = _window_events(rounds, ctx, None)
+    order = [e[1] for e in events]
+    cap = max(1, ctx)
+    expect = []
+    for i, rnd in enumerate(rounds):
+        if i >= cap:
+            expect.append(rounds[i - cap])
+        expect.append(rnd)
+    expect += rounds[max(0, len(rounds) - cap):]
+    assert order == expect
+    if hasattr(s, "send_phases"):
+        phases, key = s.send_phases(False)
+        assert key is None and phases == (s.rounds, s.rounds)
+        # one window over offset-major rounds never overlaps two peers
+        # at contexts 1
+        assert s.overlap_share(1, tile_fused=False) == 0.0
 
 
 # ------------------------------------------------------ ring rotation rounds

@@ -288,6 +288,72 @@ else:
         pass
 
 
+def _replay_into(probe, phases, cap, key):
+    """Drive ``probe`` the way a kernel does: each phase's rounds through a
+    SendWindow whose hooks record issues and retirements, drained at the
+    phase's end."""
+    from repro.core.schedule import SendWindow
+
+    def k(rnd):
+        return None if key is None else key(rnd)
+
+    for rounds in phases:
+        window = SendWindow(
+            cap, start=lambda rnd: probe.issue(*rnd, key=k(rnd)),
+            wait=lambda rnd: probe.wait_send(k(rnd)))
+        for rnd in rounds:
+            window.push(rnd, key=k(rnd))
+        window.drain()
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_probe_reports_peers_in_flight(fused):
+    """ScheduleProbe.check on moe_dispatch's send phases: the tile-fused
+    order keeps a send to every peer in flight under contexts 1, and its
+    observed overlap share equals the schedule's; one offset-major window
+    never has two peers in flight at contexts 1."""
+    sched = make_schedule((43, 14, 5, 2), 8)
+    phases, key = sched.send_phases(fused)
+    probe = ScheduleProbe()
+    _replay_into(probe, phases, 1, key)
+    summary = probe.check(sched, 1, phases=phases, key=key)
+    assert summary["max_depth"] == 1
+    assert summary["rounds"] == sum(len(p) for p in phases)
+    share = sched.overlap_share(1, tile_fused=fused, elide_dummy=False)
+    assert summary["overlap_share"] == share
+    if fused:
+        assert summary["peers_in_flight"] == sched.n and share > 0.9
+    else:
+        assert summary["peers_in_flight"] == 1 and share == 0.0
+
+
+def test_probe_rejects_keyed_window_faults():
+    """The probe catches a round issued out of order, a window past its
+    cap and a window left undrained where the next one opens."""
+    from repro.core.schedule import offset_key
+    sched = make_schedule((43, 14, 5, 2), 8)
+    phases, key = sched.send_phases(True)
+    # offset-major rounds where block-major ones were expected
+    probe = ScheduleProbe()
+    _replay_into(probe, (sched.rounds, phases[1]), 1, key)
+    with pytest.raises(AssertionError, match="issue order"):
+        probe.check(sched, 1, phases=phases, key=key)
+    # two rounds to one peer in flight under contexts 1
+    probe = ScheduleProbe()
+    _replay_into(probe, phases, 2, key)
+    with pytest.raises(AssertionError, match="exceeded"):
+        probe.check(sched, 1, phases=phases, key=key)
+    # the dispatch window not drained before the combine window opens
+    probe = ScheduleProbe()
+    _replay_into(probe, phases, 1, key)
+    drains = [i for i, e in enumerate(probe.events)
+              if e[0] == "wait_send"][len(phases[0]) - 4:len(phases[0])]
+    probe.events = [e for i, e in enumerate(probe.events)
+                    if i not in drains]
+    with pytest.raises(AssertionError, match="not drained"):
+        probe.check(sched, 1, phases=phases, key=offset_key)
+
+
 def test_device_phases_switch_is_in_the_jit_key():
     """A step traced with phases off is never served from the jit entry
     traced with them on, nor the reverse; off, ``phase`` adds nothing to

@@ -72,6 +72,88 @@ def test_dispatch_knob_grid_passes_l0(knobs):
         assert rep.ok, f"{knobs} cx={cx}: {rep.summary()}"
 
 
+@pytest.mark.parametrize("cx", TUNABLES["contexts"])
+def test_tile_fused_dispatch_lowers_block_major_keyed_windows(cx):
+    """The tile-fused lowering issues DispatchSchedule.send_phases' rounds
+    (block-major, each offset in a window of its own), passes l0, and
+    carries one window per offset: every wait_send names the window of a
+    DMA still in flight, and sends to several peers overlap."""
+    sched = make_schedule((43, 14, 5, 2), 8, True)
+    prog = lower_dispatch(sched, cx, tile_fused=True, combine_tile=4)
+    rep = verify_program(prog)
+    assert rep.ok, rep.summary()
+    (order, combine), key = sched.send_phases(True, 4)
+    for r in range(sched.n):
+        dmas = [op for op in prog.ops[r] if op.kind == "dma"]
+        disp = [op for op in dmas if op.phase == "dispatch"]
+        assert [op.rnd for op in disp] == list(range(len(order)))
+        assert [op.dst for op in disp] == [(r - off) % sched.n
+                                           for off, _j in order]
+        assert [op.key for op in disp] == [key(rnd) for rnd in order]
+        comb = [op for op in dmas if op.phase == "combine"]
+        assert [op.key for op in comb] == [key(rnd) for rnd in combine]
+        assert len({op.key for op in dmas}) == sched.n
+        inflight, most = [], 0
+        for op in prog.ops[r]:
+            if op.kind == "dma":
+                inflight.append(op.key)
+                most = max(most, len(set(inflight)))
+                assert inflight.count(op.key) <= cx
+            elif op.kind == "wait_send":
+                inflight.remove(op.key)
+        assert not inflight and most == sched.n
+
+
+@pytest.mark.parametrize("planted", ["overflow", "drain"])
+def test_tile_fused_dispatch_reports_planted_window_faults(planted):
+    """A per-key window overflow (a retire dropped mid-stream) or a
+    missing drain (the last retire dropped) is still reported."""
+    sched = make_schedule((43, 14, 5, 2), 8, True)
+    prog = lower_dispatch(sched, 1, tile_fused=True)
+    ops = prog.ops[0]
+    retires = [i for i, op in enumerate(ops) if op.kind == "wait_send"]
+    # with contexts 1 the first retire precedes a push to its own window
+    i = retires[0] if planted == "overflow" else retires[-1]
+    mut = prog.clone()
+    del mut.ops[0][i]
+    rep = verify_program(mut)
+    assert not rep.ok
+    code = "window-overflow" if planted == "overflow" else "missing-drain"
+    assert rep.errors[0].code == code, rep.summary()
+    assert rep.errors[0].rank == 0
+    if planted == "overflow":
+        assert f"window {ops[i].key}" in rep.errors[0].detail
+
+
+@pytest.mark.parametrize("knobs", [
+    dict(barrier=True, pipelined=False),
+    dict(pipelined=True),
+])
+def test_per_source_dispatch_lowers_one_offset_major_window(knobs):
+    """The DeepEP (per-source) lowerings keep sched.rounds through one
+    window: no DMA carries a key and the depth is the unkeyed mirror's."""
+    from repro.core.schedule import send_window_depths
+    sched = make_schedule((43, 14, 5, 2), 8, True)
+    prog = lower_dispatch(sched, 2, **knobs)
+    assert verify_program(prog).ok
+    for r in range(sched.n):
+        disp = [op for op in prog.ops[r]
+                if op.kind == "dma" and op.phase == "dispatch"]
+        assert [op.dst for op in disp] == [(r - off) % sched.n
+                                           for off, _j in sched.rounds]
+        assert {op.key for op in prog.ops[r]} == {None}
+        depth, depths = 0, []
+        for op in prog.ops[r]:
+            if op.kind == "wait_send":
+                depth -= 1
+            elif op.kind == "dma" and op.phase == "dispatch":
+                depth += 1
+                depths.append(depth)
+            elif op.kind == "dma":
+                break                      # the combine window opens
+        assert depths == send_window_depths(sched.rounds, 2)
+
+
 @pytest.mark.parametrize("fused", [True, False])
 @pytest.mark.parametrize("counter", [True, False])
 def test_broadcast_schedules_pass_l0(fused, counter):
